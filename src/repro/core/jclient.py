@@ -156,6 +156,7 @@ class JClient:
         self._fleet_skip: set = set()
         self.n_evaluated = 0
         self.n_compiled = 0
+        self.build_seconds: List[float] = []    # wall time of each build_fn
         if cache_dir is not None:
             os.makedirs(cache_dir, exist_ok=True)
 
@@ -251,7 +252,9 @@ class JClient:
                 else:
                     self._fleet_misses += 1
         if built is None:
+            t0 = time.monotonic()
             built = self.build_fn(tc)
+            self.build_seconds.append(time.monotonic() - t0)
             self.n_compiled += 1
             if self.cache_dir is not None:
                 self._disk_store(key, built)

@@ -26,7 +26,7 @@ buffers and runs every hot step as one jitted call:
   regime.  Below the threshold the active set is the full archive and the
   posterior matches the numpy path to float64 round-off.
 * **float64 without global flags** — every device call runs inside
-  ``jax.experimental.enable_x64()``, a thread-local scope, so GP parity
+  ``jax.enable_x64(True)``, a thread-local scope, so GP parity
   with the float64 numpy reference does not require flipping the process-
   wide ``jax_enable_x64`` switch under the rest of the suite (kernel and
   model code elsewhere still sees default float32).
@@ -45,7 +45,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.scipy.linalg import solve_triangular
 
 
@@ -271,7 +270,7 @@ class JaxIncrementalGP:
         if self._cap >= need and self._dim == dim:
             return
         cap = _pow2(need)
-        with enable_x64():
+        with jax.enable_x64(True):
             xb = jnp.zeros((cap, dim), jnp.float64)
             lb = jnp.zeros((cap, cap), jnp.float64)
             lib = jnp.zeros((cap, cap), jnp.float64)
@@ -321,7 +320,7 @@ class JaxIncrementalGP:
         self._ensure_cap(self._n + B, d)
         xpad = np.zeros((B, d))
         xpad[:m] = xa
-        with enable_x64():
+        with jax.enable_x64(True):
             self._xb, self._lb, self._lib, ok = _append_jit(
                 self._xb, self._lb, self._lib,
                 np.int32(self._n), np.int32(m), jnp.asarray(xpad),
@@ -335,7 +334,7 @@ class JaxIncrementalGP:
             self._refactor()
 
     def _refactor(self) -> None:
-        with enable_x64():
+        with jax.enable_x64(True):
             self._lb, self._lib = _refactor_jit(
                 self._xb, np.int32(self._n), self.ls, self.noise, self.signal)
         self.n_refactors += 1
@@ -357,14 +356,14 @@ class JaxIncrementalGP:
         if self._xb is not None and self._dim == d and _pow2(m) <= self._cap:
             xpad = np.zeros((thr, d))
             xpad[:m] = xa
-            with enable_x64():
+            with jax.enable_x64(True):
                 self._xb = _rethin_jit(self._xb, jnp.asarray(xpad),
                                        np.int32(m))
             self.n_rethins += 1
         else:
             self._n = 0
             self._ensure_cap(m, d)
-            with enable_x64():
+            with jax.enable_x64(True):
                 self._xb = (jnp.zeros((self._cap, d), jnp.float64)
                             .at[:m, :].set(jnp.asarray(xa)))
         self._n = m
@@ -410,7 +409,7 @@ class JaxIncrementalGP:
         ya = self._active_targets(np.asarray(y, float))
         self._ym = float(np.mean(ya))
         self._ys = float(np.std(ya)) or 1.0
-        with enable_x64():
+        with jax.enable_x64(True):
             self._alpha1 = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym) / self._ys)[:, None])
         return self
@@ -424,7 +423,7 @@ class JaxIncrementalGP:
         self._ym_m = ya.mean(axis=0)
         std = ya.std(axis=0)
         self._ys_m = np.where(std > 0, std, 1.0)
-        with enable_x64():
+        with jax.enable_x64(True):
             self._alpha_m = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym_m) / self._ys_m))
         return self
@@ -439,13 +438,13 @@ class JaxIncrementalGP:
         # jnp.asarray silently truncates the queries to float32 and every
         # downstream GEMM runs on f32-rounded inputs (≈1e-7 posterior error
         # — the exact silent-precision bug this module exists to avoid)
-        with enable_x64():
+        with jax.enable_x64(True):
             xq = jnp.asarray(xq)
         return xq, len(xs)
 
     def predict(self, xs: np.ndarray):
         xq, M = self._pad_pool(xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             mu, var = _predict_jit(self._xb, self._lib, self._alpha1,
                                    np.int32(self._n), xq, self.ls, self.signal)
         mu = np.asarray(mu)[:M, 0]
@@ -454,7 +453,7 @@ class JaxIncrementalGP:
 
     def predict_multi(self, xs: np.ndarray):
         xq, M = self._pad_pool(xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             mu, var = _predict_jit(self._xb, self._lib, self._alpha_m,
                                    np.int32(self._n), xq, self.ls, self.signal)
         mu = np.asarray(mu)[:M] * self._ys_m + self._ym_m
@@ -463,7 +462,7 @@ class JaxIncrementalGP:
 
     def predict_mean_multi(self, xs: np.ndarray) -> np.ndarray:
         xq, M = self._pad_pool(xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             mu = _predict_mean_jit(self._xb, self._alpha_m, np.int32(self._n),
                                    xq, self.ls, self.signal)
         return np.asarray(mu)[:M] * self._ys_m + self._ym_m
@@ -490,7 +489,7 @@ class JaxIncrementalGP:
         pad = np.repeat([[ref[0], front[-1, 1]]], F - len(front), axis=0)
         fpad = np.vstack([front, pad])
         xq, M = self._pad_pool(xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             s = _ehvi_jit(self._xb, self._alpha_m, np.int32(self._n), xq,
                           jnp.asarray(fpad), jnp.asarray(ref),
                           jnp.asarray(self._ym_m), jnp.asarray(self._ys_m),
@@ -550,7 +549,7 @@ class JaxIncrementalGP:
         if n:
             d = state["xb"].shape[1]
             self._ensure_cap(n, d)     # allocates zeroed pow2 device buffers
-            with enable_x64():
+            with jax.enable_x64(True):
                 self._xb = self._xb.at[:n, :].set(jnp.asarray(state["xb"]))
                 self._lb = self._lb.at[:n, :n].set(jnp.asarray(state["lb"]))
                 self._lib = self._lib.at[:n, :n].set(jnp.asarray(state["lib"]))

@@ -20,13 +20,16 @@ parity suite only has to pin the two overridden calls.
 On non-TPU backends the kernels run in Pallas interpret mode (float64
 exact), so CPU CI exercises the real kernel bodies; block sizes are
 clamped to the current buffer capacity, keeping small-n traces cheap.
+On a TPU the kernels would lower through Mosaic, which has no float64, so
+construction refuses there instead of failing mid-sweep; a float32 device
+GP is what would lift that.
 """
 from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.search.gp_jax import JaxIncrementalGP, _pow2_small
 from repro.kernels import gp_ops
@@ -54,6 +57,11 @@ class PallasIncrementalGP(JaxIncrementalGP):
                  signal: float = 1.0, inducing_threshold=None,
                  inducing_overflow: float = 1.25, block: int = 256,
                  pool_block: int = 256):
+        if not gp_ops._interpret():
+            raise NotImplementedError(
+                "gp_mode='pallas' keeps float64 GP state, and Mosaic (the "
+                f"{jax.default_backend()} Pallas compiler) has no float64; "
+                "use gp_mode='jax' on this backend")
         super().__init__(lengthscale=lengthscale, noise=noise, signal=signal,
                          inducing_threshold=inducing_threshold,
                          inducing_overflow=inducing_overflow)
@@ -70,7 +78,7 @@ class PallasIncrementalGP(JaxIncrementalGP):
         forms as ``gp_jax._kern``: scalar ls divides distances by ls²
         (bit-for-bit historical path); an ARD vector pre-scales inputs."""
         if np.ndim(self.ls):
-            with enable_x64():
+            with jax.enable_x64(True):
                 ils = jnp.asarray(1.0 / np.asarray(self.ls, float))
             return 1.0, ils
         return float(self.ls) ** 2, None
@@ -83,7 +91,7 @@ class PallasIncrementalGP(JaxIncrementalGP):
         xpad = np.zeros((B, d))
         xpad[:m] = xa
         ls2, ils = self._ls_args()
-        with enable_x64():
+        with jax.enable_x64(True):
             self._xb, self._lb, self._lib, ok = gp_ops.gp_append(
                 self._xb, self._lb, self._lib,
                 np.int32(self._n), np.int32(m), jnp.asarray(xpad), ils,
@@ -131,7 +139,7 @@ class PallasIncrementalGP(JaxIncrementalGP):
                         np.asarray(self._ys_m, float)])
         xq, M = self._pad_pool(xs)
         ls2, ils = self._ls_args()
-        with enable_x64():
+        with jax.enable_x64(True):
             s = gp_ops.gp_fused_ehvi(
                 self._xb, self._alpha_m, np.int32(self._n), xq,
                 jnp.asarray(stair), jnp.asarray(ymd), ils,

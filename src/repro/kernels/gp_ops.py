@@ -25,9 +25,13 @@ Both kernels follow the in-repo ``flash_attention.py``/``ssd_scan.py``
 idiom: pltpu block specs, VMEM scratch carried across the minor grid
 dimension, and an interpret-mode fallback (``jax.default_backend() !=
 "tpu"``) so CPU CI exercises the exact kernel code.  The GP state is
-float64 (scoped ``enable_x64`` in the caller), which interpret mode
-executes exactly; a real-TPU deployment lowers at float32 — the parity
-suite pins the float64 interpret path against the numpy reference.
+float64 (scoped ``jax.enable_x64(True)`` in the caller), which interpret
+mode executes exactly; the parity suite pins that path against the numpy
+reference.  There is no TPU path yet: Mosaic has no float64, and at
+float32 inside the x64 scope it rejects the i64 index maps, so
+``PallasIncrementalGP`` refuses to start on a TPU.  Both kernels do lower
+to ``tpu_custom_call`` at float32 with x64 off — the starting point for a
+float32 device GP.
 
 Lengthscales: ``ls2`` is a *static* scalar (isotropic ls², retraced only on
 a hyper refresh).  ARD per-dimension lengthscales are handled by the caller

@@ -51,16 +51,22 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=256, block_kv=256
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, a_log, b, c, dt, *, chunk=256):
-    """Chunked SSD; pads S to a chunk multiple (dt=0 ⇒ pads are inert)."""
+    """Chunked SSD; pads S to a chunk multiple (dt=0 ⇒ pads are inert).
+
+    x: (B,S,H,P); a_log, dt: (B,S,H); b, c: (B,S,N).  Heads move ahead of
+    the sequence for the kernel, so its blocks are (chunk, P) and
+    (1, chunk) tiles instead of single-head slivers of (S, H, ...)."""
     s = x.shape[1]
     chunk = min(chunk, max(8, 1 << (s - 1).bit_length()))
     pad = (-s) % chunk
     if pad:
         padf = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
         x, a_log, b, c, dt = map(padf, (x, a_log, b, c, dt))
-    y, state = _ssd.ssd_scan_fwd(x, a_log, b, c, dt, chunk=chunk,
+    heads_major = lambda t: t.swapaxes(1, 2)[:, :, None, :]     # (B,H,1,S)
+    y, state = _ssd.ssd_scan_fwd(x.swapaxes(1, 2), heads_major(a_log), b, c,
+                                 heads_major(dt), chunk=chunk,
                                  interpret=_interpret())
-    return y[:, :s], state
+    return y.swapaxes(1, 2)[:, :s], state
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_t"))

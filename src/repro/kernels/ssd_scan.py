@@ -31,34 +31,42 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, dt_ref, y_ref, state_ref,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)      # (Q, P)
-    a = a_ref[0, :, 0].astype(jnp.float32)         # (Q,)
+    x = x_ref[0, 0].astype(jnp.float32)            # (Q, P)
+    a = a_ref[0, 0].astype(jnp.float32)            # (1, Q) row
     bb = b_ref[0].astype(jnp.float32)              # (Q, N)
     cc = c_ref[0].astype(jnp.float32)              # (Q, N)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)       # (Q,)
+    dt = dt_ref[0, 0].astype(jnp.float32)          # (1, Q) row
 
-    cum = jnp.cumsum(a)                            # (Q,)
-    cb = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)     # (Q, Q)
-    decay = jnp.exp(cum[:, None] - cum[None, :])
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    sm = jnp.where(cols <= rows, cb * decay * dt[None, :], 0.0)
+    causal = cols <= rows
+    diag = cols == rows
+    # chunk-local inclusive cumsum as a column (cum_i), then moved to a row
+    # (cum_j) and dt to a column through the diagonal: masked lane/sublane
+    # reductions, which Mosaic lowers, in place of cumsum and transposes
+    cum = jnp.sum(jnp.where(causal, a, 0.0), axis=1, keepdims=True)   # (Q, 1)
+    cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0, keepdims=True)
+    dt_col = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)
+
+    cb = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)     # (Q, Q)
+    decay = jnp.exp(cum - cum_row)
+    sm = jnp.where(causal, cb * decay * dt, 0.0)
     y = jax.lax.dot_general(sm, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)      # (Q, P)
 
     state = state_scr[...]                         # (P, N)
     y += jax.lax.dot_general(cc, state, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * jnp.exp(cum)[:, None]
+                             preferred_element_type=jnp.float32) * jnp.exp(cum)
 
-    total = cum[-1]
-    rem = jnp.exp(total - cum)                     # (Q,)
-    dx = x * (dt * rem)[:, None]                   # (Q, P)
+    total = jnp.sum(a, axis=1, keepdims=True)      # (1, 1)
+    rem = jnp.exp(total - cum)                     # (Q, 1)
+    dx = x * (dt_col * rem)                        # (Q, P)
     new_state = state * jnp.exp(total) + jax.lax.dot_general(
         dx, bb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     state_scr[...] = new_state
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -66,11 +74,13 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, dt_ref, y_ref, state_ref,
 
 
 def ssd_scan_fwd(x, a_log, b, c, dt, *, chunk=256, interpret=False):
-    """x: (B,S,H,P); a_log, dt: (B,S,H); b, c: (B,S,N).  S % chunk == 0.
+    """Kernel layout, heads major so every block tiles:
+    x: (B,H,S,P); a_log, dt: (B,H,1,S); b, c: (B,S,N).  S % chunk == 0,
+    and on a TPU chunk is a multiple of 128 or equals S.
 
-    Returns (y (B,S,H,P), state (B,H,P,N) fp32).
+    Returns (y (B,H,S,P), state (B,H,P,N) fp32).
     """
-    bsz, s, h, p = x.shape
+    bsz, h, s, p = x.shape
     n = b.shape[-1]
     assert s % chunk == 0
     nc = s // chunk
@@ -80,18 +90,18 @@ def ssd_scan_fwd(x, a_log, b, c, dt, *, chunk=256, interpret=False):
         kernel,
         grid=(bsz, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],

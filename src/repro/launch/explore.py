@@ -1,7 +1,3 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 """The JExplore driver: JHost + search algorithm + a real model workload.
 
 Reproduces the paper's experiments on the TPU adaptation:
@@ -10,14 +6,17 @@ Reproduces the paper's experiments on the TPU adaptation:
         --workload llama2-7b --samples 200 --algorithm random \
         --clients 2 --out results/llama2_explore.csv
 
-Each "board" is a v5e-8 inference slice (tp=8); the workload is the paper's
-generation task (prompt prefill + 150 greedy decode tokens).  Hardware-ladder
-knobs (clock/HBM/ICI) re-evaluate the analytic JMeasure model against the
-cached compiled artifact — exactly like re-clocking a Jetson without
-redeploying the network; sw knobs recompile (JClient caches by fingerprint).
+Each "board" is a tensor-parallel slice of ``--chips`` devices (default:
+every device JAX sees, so a v5e host's four chips give tp=4); the workload
+is the paper's generation task (prompt prefill + 150 greedy decode tokens).
+Programs are compiled for the devices present and read, not run.
+Hardware-ladder knobs (clock/HBM/ICI) re-evaluate the analytic JMeasure
+model against the cached compiled artifact — exactly like re-clocking a
+Jetson without redeploying the network; sw knobs recompile (JClient caches
+by fingerprint).
 
 ``--shape train_4k`` etc. switch the workload to a training/prefill/decode
-step of the assigned architectures on a dp×tp slice of the same 8 devices.
+step of the assigned architectures on a dp×tp slice of the same devices.
 
 GP surrogate modes and flags (bayesopt/pal only)
 ------------------------------------------------
@@ -50,11 +49,12 @@ GP surrogate modes and flags (bayesopt/pal only)
   other healthy clients are mirrored elsewhere (first answer wins).
 """
 import argparse
+import os
 import threading
 import time
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--workload", default="llama2-7b", help="arch id")
     p.add_argument("--shape", default="generate",
@@ -66,7 +66,8 @@ def parse_args():
     p.add_argument("--algorithm", default="random",
                    choices=["random", "grid", "nsga2", "bayesopt", "pal"])
     p.add_argument("--clients", type=int, default=2)
-    p.add_argument("--chips", type=int, default=8, help="chips per board")
+    p.add_argument("--chips", type=int, default=None,
+                   help="chips per board (default: every device JAX sees)")
     p.add_argument("--prompt-len", type=int, default=64)
     p.add_argument("--gen-tokens", type=int, default=150)
     p.add_argument("--out", default="results/explore.csv")
@@ -176,19 +177,36 @@ def parse_args():
                         "with probability P (exercises first-answer-wins)")
     p.add_argument("--chaos-seed", type=int, default=0,
                    help="RNG seed for the fault-injection wrapper")
-    return p.parse_args()
+    return with_default_chips(p.parse_args(argv))
+
+
+def with_default_chips(args):
+    """``--chips`` left unset means the devices this process actually has."""
+    if args.chips is None:
+        import jax
+
+        args.chips = len(jax.devices())
+    return args
 
 
 def make_build_fn(args, jc):
     """Workload adapter: TestConfig -> (Artifact, meta).  Injected into
-    JClient — 'the workloads can be anything' (paper §III)."""
+    JClient — 'the workloads can be anything' (paper §III).
+
+    On a TPU the roofline model must hold the chip's peaks: an unknown
+    ``device_kind`` is refused here, before any build."""
     import jax
 
     from repro.configs import SHAPES, get_arch, reduced
     from repro.launch.build import build_cell, build_generation
     from repro.launch.mesh import make_mesh_dp_tp
     from repro.roofline.analysis import summarize
+    from repro.roofline.hw import chip_peaks
     from repro.roofline.traffic import analytic_hbm_bytes_per_device
+
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        chip_peaks(dev.device_kind)
 
     def build(tc):
         arch = get_arch(tc.arch)
@@ -269,11 +287,15 @@ def generation_space(arch, chips):
     return DesignSpace(knobs)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Run one sweep; returns its records and compile counters."""
+    args = parse_args(argv)
     from repro.configs import get_arch, SHAPES
     from repro.core import (ALGORITHMS, JConfig, JHost, ResultStore,
                             tpu_pod_space, hypervolume)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     arch = get_arch(args.workload)
     if args.reduced:
@@ -354,9 +376,10 @@ def main():
     front = store.pareto_front(["time_s", "power_w"])
     ref = pts.max(0) * 1.1
     compiles = sum(c.n_compiled for c in clients)
+    build_s = [s for c in clients for s in c.build_seconds]
     print(f"[explore] {len(ok)} configs in {dt:.1f}s "
-          f"({len(ok) / max(dt, 1e-9):.1f} evals/s; {compiles} compiles, "
-          f"{len(ok)-compiles} cache hits)")
+          f"({len(ok) / max(dt, 1e-9):.1f} evals/s; {compiles} compiles "
+          f"in {sum(build_s):.1f}s, {len(ok)-compiles} cache hits)")
     if args.cache_dir is not None or fleet_store is not None:
         from repro.launch.report import cache_effectiveness
 
@@ -369,6 +392,8 @@ def main():
     print(f"[explore] time range  [{pts[:,0].min():.3f}, {pts[:,0].max():.3f}] s")
     print(f"[explore] power range [{pts[:,1].min():.1f}, {pts[:,1].max():.1f}] W")
     print(f"[explore] results -> {args.out}")
+    return {"records": list(store.records), "wall_s": dt,
+            "build_seconds": [c.build_seconds for c in clients]}
 
 
 if __name__ == "__main__":

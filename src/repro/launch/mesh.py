@@ -9,12 +9,9 @@ import jax
 
 
 def _make(shape, axes):
-    # jax < 0.5 has neither sharding.AxisType nor the axis_types kwarg; every
-    # axis defaults to Auto there, which is exactly what we request on >= 0.5
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """A mesh on the first prod(shape) devices, every axis Auto-sharded."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,7 +9,7 @@ import argparse
 import time
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="tinyllama-1.1b")
     p.add_argument("--reduced", action="store_true")
@@ -18,18 +18,22 @@ def parse_args():
     p.add_argument("--gen", type=int, default=32)
     p.add_argument("--dtype", default="float32")
     p.add_argument("--seed", type=int, default=0)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Generate once; returns the arch and the ``GenerationResult``."""
+    args = parse_args(argv)
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import get_arch, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import BuildFlags, Model
     from repro.serve import Engine
+
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:
@@ -60,6 +64,7 @@ def main():
           f"generated={res.n_generated} in {dt:.2f}s "
           f"({args.batch*args.gen/dt:.1f} tok/s)")
     print("[serve] first sequence:", res.tokens[0][:16].tolist())
+    return arch, res
 
 
 if __name__ == "__main__":

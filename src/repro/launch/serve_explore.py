@@ -1,7 +1,3 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 """Multi-tenant exploration service over one loopback fleet.
 
 Where ``launch.explore`` runs ONE sweep and exits, this entrypoint stands up
@@ -46,12 +42,13 @@ Each line gets a one-line JSON reply on stdout.  EOF (or ``{"cmd":
 """
 import argparse
 import json
+import os
 import sys
 import threading
 import time
 
 from repro.launch.explore import (generation_space, make_build_fn,
-                                  start_fleet)
+                                  start_fleet, with_default_chips)
 
 
 def parse_args():
@@ -62,7 +59,8 @@ def parse_args():
     p.add_argument("--reduced", action="store_true",
                    help="shrink the arch so smoke runs compile in seconds")
     p.add_argument("--clients", type=int, default=2)
-    p.add_argument("--chips", type=int, default=8, help="chips per board")
+    p.add_argument("--chips", type=int, default=None,
+                   help="chips per board (default: every device JAX sees)")
     p.add_argument("--prompt-len", type=int, default=64)
     p.add_argument("--gen-tokens", type=int, default=150)
     p.add_argument("--tenants", default=None, metavar="SPEC.json",
@@ -106,7 +104,7 @@ def parse_args():
                         "namespaced under this root "
                         "(durable.tenant_checkpoint_dir)")
     p.add_argument("--checkpoint-every", type=int, default=25)
-    return p.parse_args()
+    return with_default_chips(p.parse_args())
 
 
 def make_sweep_factory(args, space, jc, need_fp):
@@ -188,6 +186,9 @@ def main():
     from repro.configs import get_arch, SHAPES
     from repro.core import (ExploreService, FleetArtifactStore, JConfig,
                             hypervolume, tpu_pod_space)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     arch = get_arch(args.workload)
     if args.reduced:

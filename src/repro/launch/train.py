@@ -46,6 +46,7 @@ def main():
 
     from repro.configs import get_arch, reduced
     from repro.data import DataConfig, SyntheticLM, device_put_batch
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import BuildFlags, Model
     from repro.parallel.sharding import ShardingPolicy
@@ -53,6 +54,7 @@ def main():
                              adamw, cosine_schedule, init_train_state,
                              make_train_step)
 
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.reduced:
         arch = reduced(arch)

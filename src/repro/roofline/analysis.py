@@ -122,8 +122,6 @@ class Artifact:
 
 def summarize(compiled, n_devices: int, with_ops: bool = False) -> Artifact:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax < 0.5 returns [per-device dict]
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     txt = compiled.as_text()
     coll = collective_wire_bytes(txt, n_devices)

@@ -20,11 +20,27 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-# -- TPU v5e per-chip peaks (assignment-specified constants) -----------------
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
+# -- per-chip peaks, keyed by jax ``device_kind`` ------------------------------
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, HBM at
+# 819 GB/s, 1,600 Gbit/s ICI over 4 links (50 GB/s each).  JAX reports the
+# chip, and a described v5e topology, as "TPU v5 lite".
+_V5E = {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw_per_link": 50e9}
+CHIP_PEAKS = {"TPU v5 lite": _V5E}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of a chip the roofline model holds; any other kind is an error
+    (its numbers would be another chip's)."""
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(f"no roofline peaks for device kind {device_kind!r}; "
+                         f"known: {sorted(CHIP_PEAKS)}")
+    return CHIP_PEAKS[device_kind]
+
+
+PEAK_FLOPS_BF16 = _V5E["flops_bf16"]               # FLOP/s
 PEAK_FLOPS_FP32 = PEAK_FLOPS_BF16 / 4
-HBM_BW = 819e9                 # B/s
-ICI_BW_PER_LINK = 50e9         # B/s per link (formula uses chips × link_bw)
+HBM_BW = _V5E["hbm_bw"]                            # B/s
+ICI_BW_PER_LINK = _V5E["ici_bw_per_link"]          # B/s per link (× chips)
 
 # -- modeled power envelope ---------------------------------------------------
 IDLE_W = 75.0

@@ -56,7 +56,7 @@ def test_crash_restart_identical(tmp_path):
 
 EXPLORE = [sys.executable, "-m", "repro.launch.explore", "--workload",
            "llama2-7b", "--reduced", "--samples", "12", "--algorithm",
-           "bayesopt", "--clients", "1", "--prompt-len", "8",
+           "bayesopt", "--clients", "1", "--chips", "1", "--prompt-len", "8",
            "--gen-tokens", "4", "--seed", "5", "--batch-size", "4",
            "--checkpoint-every", "6"]
 

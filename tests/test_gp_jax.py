@@ -7,8 +7,6 @@ schedule riding the device buffers."""
 import numpy as np
 import pytest
 
-gp_jax = pytest.importorskip("repro.core.search.gp_jax")
-
 from repro.core.search.bayesopt import (BayesOpt, GP, IncrementalGP, PAL,
                                         ehvi_improvements)
 from repro.core.search.gp_jax import JaxIncrementalGP

@@ -6,8 +6,6 @@ BayesOpt/PAL pick-sequence equality against the numpy reference."""
 import numpy as np
 import pytest
 
-gp_pallas = pytest.importorskip("repro.core.search.gp_pallas")
-
 from repro.core.search.bayesopt import (BayesOpt, IncrementalGP, PAL,
                                         ehvi_improvements, tune_lengthscale)
 from repro.core.search.gp_jax import JaxIncrementalGP

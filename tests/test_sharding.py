@@ -120,7 +120,7 @@ from repro.launch.mesh import make_mesh_dp_tp
 from repro.parallel.pipeline import pipeline_apply, bubble_fraction
 
 from repro.launch.mesh import _make
-mesh = _make((4,), ("pipe",))   # jax<0.5-compatible make_mesh
+mesh = _make((4,), ("pipe",))   # Auto-axis make_mesh
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 
 def stage_fn(w, x):
@@ -152,7 +152,7 @@ from jax.sharding import PartitionSpec as P
 from repro.parallel.compress import psum_int8
 
 from repro.launch.mesh import _make
-mesh = _make((8,), ("data",))   # jax<0.5-compatible make_mesh
+mesh = _make((8,), ("data",))   # Auto-axis make_mesh
 x = jax.random.normal(jax.random.key(0), (8, 128))
 
 def f(x):
