@@ -95,6 +95,7 @@ import numpy as np
 
 from repro.core.jconfig import JConfig, TestConfig
 from repro.core.jmeasure import DEFAULT_MEASURES, JMeasure
+from repro.core.tracing import span
 from repro.core.transport import (ARTIFACT_CHUNK, ARTIFACT_CMDS,
                                   ARTIFACT_FETCH, ARTIFACT_MISS,
                                   ARTIFACT_PUT, ARTIFACT_QUERY, BATCH_CMD,
@@ -253,7 +254,8 @@ class JClient:
                     self._fleet_misses += 1
         if built is None:
             t0 = time.monotonic()
-            built = self.build_fn(tc)
+            with span("jx.client.build"):
+                built = self.build_fn(tc)
             self.build_seconds.append(time.monotonic() - t0)
             self.n_compiled += 1
             if self.cache_dir is not None:
@@ -538,6 +540,11 @@ class JClient:
         ``evaluate`` schema; metric values are bit-identical to N scalar
         calls (the vectorized sweep mirrors the scalar arithmetic op-for-op).
         """
+        with span("jx.client.batch", n=len(tcs),
+                  cid=tcs[0].config_id if tcs else None):
+            return self._evaluate_batch(tcs)
+
+    def _evaluate_batch(self, tcs: Sequence[TestConfig]) -> List[dict]:
         results: List[Optional[dict]] = [None] * len(tcs)
         groups: Dict[tuple, List[int]] = {}
         for i, tc in enumerate(tcs):
@@ -551,9 +558,11 @@ class JClient:
             cols: Dict[str, np.ndarray] = {}
             try:
                 art, meta = self._artifact(key, tcs[idxs[0]])
-                hwb = self.jconfig.hw_model_batch([tcs[i].knobs for i in idxs])
-                for m in self.measures:
-                    cols.update(m.measure_batch(art, hwb, meta))
+                with span("jx.client.measure", n=len(idxs)):
+                    hwb = self.jconfig.hw_model_batch(
+                        [tcs[i].knobs for i in idxs])
+                    for m in self.measures:
+                        cols.update(m.measure_batch(art, hwb, meta))
             except Exception:
                 # scalar-parity fallback: a group-level failure (bad build, or
                 # one hw variant tripping a measure) must not fail sibling

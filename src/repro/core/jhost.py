@@ -52,6 +52,7 @@ from repro.core.jconfig import TestConfig
 from repro.core.results import ResultRecord, ResultStore
 from repro.core.scheduler import DispatchScheduler
 from repro.core.search.base import SearchAlgorithm
+from repro.core.tracing import span
 from repro.core.transport import (CLIENT_HELLO, HostTransport,
                                   is_artifact_msg, is_membership_msg)
 
@@ -213,13 +214,14 @@ class JHost:
             if want > 0:
                 if note_residency is not None:
                     note_residency(sched.resident_fingerprints())
-                if poll_ask is not None:
-                    if note_demand is not None:
-                        note_demand(min(n_samples - issued,
-                                        sched.want(lookahead=1)))
-                    cfgs = poll_ask(want, need=not sched.busy())
-                else:
-                    cfgs = search.ask(want)
+                with span("jx.host.ask", n=want):
+                    if poll_ask is not None:
+                        if note_demand is not None:
+                            note_demand(min(n_samples - issued,
+                                            sched.want(lookahead=1)))
+                        cfgs = poll_ask(want, need=not sched.busy())
+                    else:
+                        cfgs = search.ask(want)
                 if cfgs:
                     tcs_new = []
                     for knobs in cfgs:
@@ -237,9 +239,14 @@ class JHost:
                         sched.submit(tc)
                         issued += 1
             for client, tcs in sched.next_dispatches():
-                self.transport.push_many(client, [tc.to_wire() for tc in tcs])
+                with span("jx.host.dispatch", n=len(tcs),
+                          cid=tcs[0].config_id):
+                    self.transport.push_many(client,
+                                             [tc.to_wire() for tc in tcs])
 
-            msgs = self.transport.pull_many(self.poll_s)
+            with span("jx.host.pull") as sp:
+                msgs = self.transport.pull_many(self.poll_s)
+                sp.set_metadata(n_msgs=len(msgs))
             mems = [m for m in msgs if is_membership_msg(m)]
             if mems:
                 # fleet elasticity: HELLO/GOODBYE frames ride the result
@@ -258,38 +265,35 @@ class JHost:
                         fleet_store.on_message(m, self.transport.push)
                 fleet_store.tick(self.transport.push)
             if msgs:
-                sched.note_results()   # frame boundary: coalescing detection
-            for msg in msgs:
-                tc = sched.on_result(msg)
-                if tc is None:          # duplicate answer: bookkeeping only
-                    continue
-                if "knobs" not in msg:  # slim batch result: rehydrate echo
-                    msg["knobs"], msg["arch"], msg["shape"] = \
-                        tc.knobs, tc.arch, tc.shape
-                rec = ResultRecord.from_wire(msg)
-                self.store.add(rec)
-                completed += 1
-                outstanding.pop(rec.config_id, None)
-                if rec.status == "ok":
-                    y = np.asarray([rec.metrics[k] for k in objectives],
-                                   float)
-                    search.tell(rec.knobs, y)
-                    if ckpt is not None:
-                        # logged AFTER the CSV row + fold: a crash in the
-                        # window between them is the "late tell" case
-                        # restore_sweep reconciles from the CSV
-                        ckpt.log_tell(rec.config_id, rec.knobs, y)
-                if progress and completed % 10 == 0:
-                    s = sched.stats()
-                    wire = ""
-                    if "wire_out_mb" in s:
-                        wire = (f", wire {s['wire_out_mb']:.2f}/"
-                                f"{s['wire_in_mb']:.2f} MB "
-                                f"{s.get('codec', '?')}")
-                    print(f"[jhost] {completed}/{n_samples} "
-                          f"(inflight={s['inflight']:.0f}, "
-                          f"pending={s['pending']:.0f}, "
-                          f"chunk~{s['mean_chunk']:.1f}{wire})")
+                with span("jx.host.tell", n=len(msgs),
+                          cid=msgs[0].get("config_id")):
+                    # frame boundary: coalescing detection
+                    sched.note_results()
+                    for msg in msgs:
+                        tc = sched.on_result(msg)
+                        if tc is None:   # duplicate answer: bookkeeping only
+                            continue
+                        if "knobs" not in msg:
+                            # slim batch result: rehydrate the echo
+                            msg["knobs"], msg["arch"], msg["shape"] = \
+                                tc.knobs, tc.arch, tc.shape
+                        rec = ResultRecord.from_wire(msg)
+                        self.store.add(rec)
+                        completed += 1
+                        outstanding.pop(rec.config_id, None)
+                        if rec.status == "ok":
+                            y = np.asarray(
+                                [rec.metrics[k] for k in objectives], float)
+                            search.tell(rec.knobs, y)
+                            if ckpt is not None:
+                                # logged AFTER the CSV row + fold: a crash
+                                # in the window between them is the "late
+                                # tell" case restore_sweep reconciles from
+                                # the CSV
+                                ckpt.log_tell(rec.config_id, rec.knobs, y)
+                        if progress and completed % 10 == 0:
+                            self._print_progress(sched, completed,
+                                                 n_samples)
 
             # straggler sweep: requeue survivors, record terminal timeouts
             for tc, client in sched.expire():
@@ -302,18 +306,20 @@ class JHost:
 
             if ckpt is not None and completed - last_snap >= checkpoint_every:
                 last_snap = completed
-                ckpt.save({
-                    "version": 1,
-                    "event_pos": ckpt.log_pos(),
-                    "next_id": id_next,
-                    "outstanding": list(outstanding.items()),
-                    "search_state": (search.state_dict()
-                                     if hasattr(search, "state_dict")
-                                     else None),
-                    "meta": {"arch": arch, "shape": shape,
-                             "n_samples": n_samples, "completed": completed,
-                             "objectives": list(objectives)},
-                })
+                with span("jx.host.checkpoint"):
+                    ckpt.save({
+                        "version": 1,
+                        "event_pos": ckpt.log_pos(),
+                        "next_id": id_next,
+                        "outstanding": list(outstanding.items()),
+                        "search_state": (search.state_dict()
+                                         if hasattr(search, "state_dict")
+                                         else None),
+                        "meta": {"arch": arch, "shape": shape,
+                                 "n_samples": n_samples,
+                                 "completed": completed,
+                                 "objectives": list(objectives)},
+                    })
 
             if completed < n_samples and sched.stuck():
                 stats = sched.stats()
@@ -323,6 +329,18 @@ class JHost:
                     f"all clients quarantined; exploration stuck at "
                     f"{completed}/{n_samples} (scheduler stats: {stats})")
         return completed
+
+    @staticmethod
+    def _print_progress(sched, completed: int, n_samples: int) -> None:
+        s = sched.stats()
+        wire = ""
+        if "wire_out_mb" in s:
+            wire = (f", wire {s['wire_out_mb']:.2f}/"
+                    f"{s['wire_in_mb']:.2f} MB {s.get('codec', '?')}")
+        print(f"[jhost] {completed}/{n_samples} "
+              f"(inflight={s['inflight']:.0f}, "
+              f"pending={s['pending']:.0f}, "
+              f"chunk~{s['mean_chunk']:.1f}{wire})")
 
     # -- dynamic membership ----------------------------------------------------
     def _on_membership(self, msg: dict, sched: DispatchScheduler) -> None:

@@ -57,6 +57,7 @@ import numpy as np
 from repro.core.search.base import SearchAlgorithm
 from repro.core.search.hypervolume import hypervolume_2d
 from repro.core.results import nondominated_mask
+from repro.core.tracing import span
 
 GP_MODES = ("incremental", "refit", "jax", "pallas")
 # device-resident surrogates (shared JaxIncrementalGP buffer layout) vs the
@@ -592,15 +593,16 @@ class BayesOpt(SearchAlgorithm):
         if not every or len(self.history_x) - self._last_refresh < every:
             return gp
         self._last_refresh = len(self.history_x)
-        current = self._ls if self.gp_mode == "refit" else gp.ls
-        ls = tune_lengthscale(self.observed_points(), ys, current)
-        self.n_hyper_refreshes += 1
-        if self.gp_mode == "refit":
-            if not _ls_equal(ls, self._ls):
-                self._ls = ls
-                return GP(lengthscale=ls).fit_x(self.observed_points())
-            return gp
-        return gp.set_lengthscale(ls)
+        with span("jx.search.refresh"):
+            current = self._ls if self.gp_mode == "refit" else gp.ls
+            ls = tune_lengthscale(self.observed_points(), ys, current)
+            self.n_hyper_refreshes += 1
+            if self.gp_mode == "refit":
+                if not _ls_equal(ls, self._ls):
+                    self._ls = ls
+                    return GP(lengthscale=ls).fit_x(self.observed_points())
+                return gp
+            return gp.set_lengthscale(ls)
 
     def _update_front(self, y: np.ndarray) -> None:
         """O(front) incremental Pareto update, so EHVI asks never rescan all
@@ -617,17 +619,33 @@ class BayesOpt(SearchAlgorithm):
         keep = ~(np.all(y <= f, axis=1) & np.any(y < f, axis=1))   # front row
         self._front_y = np.vstack([f[keep], y[None, :]])
 
+    def _pool(self):
+        """The ask's candidate pool (``_fresh_pool``), in a
+        ``jx.search.pool`` span whose ``rows`` fall under ``pool_size`` as
+        the space runs out."""
+        with span("jx.search.pool") as sp:
+            idx, xp, flats = self._fresh_pool(self.pool_size,
+                                              exclude=self._seen)
+            sp.set_metadata(rows=len(idx))
+        return idx, xp, flats
+
+    def _flush_pending(self) -> None:
+        """One block rank-append of the tells queued since the last ask."""
+        if self._gp_pending:
+            with span("jx.search.observe", m=len(self._gp_pending)):
+                self._gp.observe(np.stack(self._gp_pending))
+                self._gp_pending.clear()
+
     def _surrogate(self) -> GP:
         """The ask-time GP: the cached incremental factor — extended by one
         rank-append over the tells since the last ask, invalidated only by
         new data — or, in refit mode, a fresh O(n³) factorisation (the
         pre-incremental path, kept for benchmarking and equivalence)."""
         if self.gp_mode in STREAM_GP_MODES:
-            if self._gp_pending:
-                self._gp.observe(np.stack(self._gp_pending))
-                self._gp_pending.clear()
+            self._flush_pending()
             return self._gp
-        return GP(lengthscale=self._ls).fit_x(self.observed_points())
+        with span("jx.search.observe", m=len(self.history_x)):
+            return GP(lengthscale=self._ls).fit_x(self.observed_points())
 
     def _scalarise(self, ys: np.ndarray) -> np.ndarray:
         lo, hi = ys.min(0), ys.max(0)
@@ -663,7 +681,7 @@ class BayesOpt(SearchAlgorithm):
                     out.append(c)
             return out
 
-        idx, xp, flats = self._fresh_pool(self.pool_size, exclude=self._seen)
+        idx, xp, flats = self._pool()
         gp = self._surrogate()   # one cached/derived factor for every pick
         gp = self._maybe_refresh(gp, ys)
 
@@ -672,32 +690,36 @@ class BayesOpt(SearchAlgorithm):
             # vectorized incremental-HVI sweep scores the whole pool; the
             # scores do not change between picks, so the n picks are simply
             # the n best-scoring unseen candidates
-            ref = ys.max(0) * 1.1 + 1e-9
-            if self.gp_mode in DEVICE_GP_MODES:
-                # fully fused on device: kernel GEMM, posterior means, and
-                # the staircase sweep happen in one device call — no (M, 2)
-                # means matrix ever lands on the host (pallas mode runs it
-                # as the tiled VMEM-resident kernel)
-                gp.fit_y_multi(ys)
-                score = gp.score_ehvi(xp, self._front_y, ref)
-            elif self.gp_mode == "incremental":
-                # one mean-only kernel sweep for both objectives, scored
-                # against the maintained front (same staircase as passing
-                # all of ys: ehvi reduces to the nondominated set anyway)
-                mus = gp.fit_y_multi(ys).predict_mean_multi(xp)
-                score = ehvi_improvements(self._front_y, ref, mus)
-            else:
-                mus = np.stack([gp.fit_y(ys[:, j]).predict(xp)[0]
-                                for j in range(ys.shape[1])], axis=1)
-                score = ehvi_improvements(ys, ref, mus)
-            self._take_best(idx, flats, np.argsort(-score), n, out)
+            with span("jx.search.acquire"):
+                ref = ys.max(0) * 1.1 + 1e-9
+                if self.gp_mode in DEVICE_GP_MODES:
+                    # fully fused on device: kernel GEMM, posterior means,
+                    # and the staircase sweep happen in one device call — no
+                    # (M, 2) means matrix ever lands on the host (pallas mode
+                    # runs it as the tiled VMEM-resident kernel)
+                    gp.fit_y_multi(ys)
+                    score = gp.score_ehvi(xp, self._front_y, ref)
+                elif self.gp_mode == "incremental":
+                    # one mean-only kernel sweep for both objectives, scored
+                    # against the maintained front (same staircase as
+                    # passing all of ys: ehvi reduces to the nondominated
+                    # set anyway)
+                    mus = gp.fit_y_multi(ys).predict_mean_multi(xp)
+                    score = ehvi_improvements(self._front_y, ref, mus)
+                else:
+                    mus = np.stack([gp.fit_y(ys[:, j]).predict(xp)[0]
+                                    for j in range(ys.shape[1])], axis=1)
+                    score = ehvi_improvements(ys, ref, mus)
+                self._take_best(idx, flats, np.argsort(-score), n, out)
             return out
 
         for _ in range(n):   # parego: fresh scalarisation per pick
-            s = self._scalarise(ys)
-            mu, sig = gp.fit_y(s).predict(xp)
-            score = expected_improvement(mu, sig, float(np.min(s)))
-            self._take_best(idx, flats, np.argsort(-score), len(out) + 1, out)
+            with span("jx.search.acquire"):
+                s = self._scalarise(ys)
+                mu, sig = gp.fit_y(s).predict(xp)
+                score = expected_improvement(mu, sig, float(np.min(s)))
+                self._take_best(idx, flats, np.argsort(-score), len(out) + 1,
+                                out)
         return out
 
 
@@ -764,6 +786,8 @@ class PAL(SearchAlgorithm):
             self._gp_pending.append(self.space.encode(knobs))
 
     _maybe_refresh = BayesOpt._maybe_refresh
+    _pool = BayesOpt._pool
+    _flush_pending = BayesOpt._flush_pending
 
     # durable state: same surrogate-swap as BayesOpt
     _STATE_SKIP = SearchAlgorithm._STATE_SKIP + ("_gp",)
@@ -791,13 +815,24 @@ class PAL(SearchAlgorithm):
                     out.append(c)
             return out
 
-        idx, xp, flats = self._fresh_pool(self.pool_size, exclude=self._seen)
+        idx, xp, flats = self._pool()
         # shared (cached in incremental mode) factor across per-objective fits
         if self.gp_mode in STREAM_GP_MODES:
-            if self._gp_pending:
-                self._gp.observe(np.stack(self._gp_pending))
-                self._gp_pending.clear()
-            gp = self._maybe_refresh(self._gp, ys).fit_y_multi(ys)
+            self._flush_pending()
+            gp = self._maybe_refresh(self._gp, ys)
+        else:
+            with span("jx.search.observe", m=len(self.history_x)):
+                gp = GP(lengthscale=self._ls).fit_x(self.observed_points())
+            gp = self._maybe_refresh(gp, ys)
+        with span("jx.search.acquire"):
+            return self._acquire(gp, ys, idx, xp, flats, n)
+
+    def _acquire(self, gp, ys, idx, xp, flats, n: int) -> List[Dict]:
+        """Posteriors over the pool, then the n widest candidates that may
+        still be Pareto-optimal."""
+        out: List[Dict] = []
+        if self.gp_mode in STREAM_GP_MODES:
+            gp = gp.fit_y_multi(ys)
             known = (self._classified_mask(flats)
                      if self.mean_only else np.zeros(len(flats), bool))
             if known.any():
@@ -814,8 +849,6 @@ class PAL(SearchAlgorithm):
                 mu, sig = gp.predict_multi(xp)
         else:
             known = np.zeros(len(flats), bool)
-            gp = GP(lengthscale=self._ls).fit_x(self.observed_points())
-            gp = self._maybe_refresh(gp, ys)
             mus, sigs = [], []
             for j in range(ys.shape[1]):
                 m, s = gp.fit_y(ys[:, j]).predict(xp)

@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
+from repro.core.tracing import span
+
 
 def jax_available() -> bool:
     """Import gate for callers that must degrade gracefully (ci_smoke)."""
@@ -65,6 +67,12 @@ def _pow2_small(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _fetch(x) -> np.ndarray:
+    """A device result copied to the host: the host waits on the chip."""
+    with span("jx.gp.fetch", bytes=int(x.nbytes)):
+        return np.asarray(x)
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +314,8 @@ class JaxIncrementalGP:
         if m == 0:
             return self
         idx = self._archive(x_new)
-        self._append_active(x_new, idx)
+        with span("jx.gp.append", cap=self._cap, rows=m):
+            self._append_active(x_new, idx)
         thr = self.inducing_threshold
         if thr is not None and self._n > int(thr * self.inducing_overflow):
             self._thin()
@@ -328,13 +337,14 @@ class JaxIncrementalGP:
         self._active_idx[self._n:self._n + m] = idx
         self._n += m
         self.n_appends += 1
-        if not bool(ok):
+        if not bool(_fetch(ok)):
             # degenerate block (duplicated rows beyond the noise jitter):
             # same fallback as the numpy LinAlgError path
             self._refactor()
 
     def _refactor(self) -> None:
-        with jax.enable_x64(True):
+        with span("jx.gp.refactor", cap=self._cap, rows=self._n), \
+                jax.enable_x64(True):
             self._lb, self._lib = _refactor_jit(
                 self._xb, np.int32(self._n), self.ls, self.noise, self.signal)
         self.n_refactors += 1
@@ -353,22 +363,24 @@ class JaxIncrementalGP:
                         .astype(np.int64))
         xa = self._ax[sel]
         m, d = xa.shape
-        if self._xb is not None and self._dim == d and _pow2(m) <= self._cap:
-            xpad = np.zeros((thr, d))
-            xpad[:m] = xa
-            with jax.enable_x64(True):
-                self._xb = _rethin_jit(self._xb, jnp.asarray(xpad),
-                                       np.int32(m))
-            self.n_rethins += 1
-        else:
-            self._n = 0
-            self._ensure_cap(m, d)
-            with jax.enable_x64(True):
-                self._xb = (jnp.zeros((self._cap, d), jnp.float64)
-                            .at[:m, :].set(jnp.asarray(xa)))
-        self._n = m
-        self._active_idx[:m] = sel
-        self._refactor()
+        with span("jx.gp.rethin", cap=self._cap, rows=m):
+            if (self._xb is not None and self._dim == d
+                    and _pow2(m) <= self._cap):
+                xpad = np.zeros((thr, d))
+                xpad[:m] = xa
+                with jax.enable_x64(True):
+                    self._xb = _rethin_jit(self._xb, jnp.asarray(xpad),
+                                           np.int32(m))
+                self.n_rethins += 1
+            else:
+                self._n = 0
+                self._ensure_cap(m, d)
+                with jax.enable_x64(True):
+                    self._xb = (jnp.zeros((self._cap, d), jnp.float64)
+                                .at[:m, :].set(jnp.asarray(xa)))
+            self._n = m
+            self._active_idx[:m] = sel
+            self._refactor()
         self.n_thins += 1
 
     def set_lengthscale(self, ls) -> "JaxIncrementalGP":
@@ -409,7 +421,8 @@ class JaxIncrementalGP:
         ya = self._active_targets(np.asarray(y, float))
         self._ym = float(np.mean(ya))
         self._ys = float(np.std(ya)) or 1.0
-        with jax.enable_x64(True):
+        with span("jx.gp.fit_y", cap=self._cap, rows=self._n), \
+                jax.enable_x64(True):
             self._alpha1 = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym) / self._ys)[:, None])
         return self
@@ -423,7 +436,8 @@ class JaxIncrementalGP:
         self._ym_m = ya.mean(axis=0)
         std = ya.std(axis=0)
         self._ys_m = np.where(std > 0, std, 1.0)
-        with jax.enable_x64(True):
+        with span("jx.gp.fit_y", cap=self._cap, rows=self._n), \
+                jax.enable_x64(True):
             self._alpha_m = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym_m) / self._ys_m))
         return self
@@ -443,29 +457,35 @@ class JaxIncrementalGP:
         return xq, len(xs)
 
     def predict(self, xs: np.ndarray):
-        xq, M = self._pad_pool(xs)
-        with jax.enable_x64(True):
-            mu, var = _predict_jit(self._xb, self._lib, self._alpha1,
-                                   np.int32(self._n), xq, self.ls, self.signal)
-        mu = np.asarray(mu)[:M, 0]
-        sig = np.sqrt(np.asarray(var)[:M])
+        with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
+            xq, M = self._pad_pool(xs)
+            with jax.enable_x64(True):
+                mu, var = _predict_jit(self._xb, self._lib, self._alpha1,
+                                       np.int32(self._n), xq, self.ls,
+                                       self.signal)
+            mu = _fetch(mu)[:M, 0]
+            sig = np.sqrt(_fetch(var)[:M])
         return mu * self._ys + self._ym, sig * self._ys
 
     def predict_multi(self, xs: np.ndarray):
-        xq, M = self._pad_pool(xs)
-        with jax.enable_x64(True):
-            mu, var = _predict_jit(self._xb, self._lib, self._alpha_m,
-                                   np.int32(self._n), xq, self.ls, self.signal)
-        mu = np.asarray(mu)[:M] * self._ys_m + self._ym_m
-        sig = np.sqrt(np.asarray(var)[:M])[:, None] * self._ys_m
+        with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
+            xq, M = self._pad_pool(xs)
+            with jax.enable_x64(True):
+                mu, var = _predict_jit(self._xb, self._lib, self._alpha_m,
+                                       np.int32(self._n), xq, self.ls,
+                                       self.signal)
+            mu = _fetch(mu)[:M] * self._ys_m + self._ym_m
+            sig = np.sqrt(_fetch(var)[:M])[:, None] * self._ys_m
         return mu, sig
 
     def predict_mean_multi(self, xs: np.ndarray) -> np.ndarray:
-        xq, M = self._pad_pool(xs)
-        with jax.enable_x64(True):
-            mu = _predict_mean_jit(self._xb, self._alpha_m, np.int32(self._n),
-                                   xq, self.ls, self.signal)
-        return np.asarray(mu)[:M] * self._ys_m + self._ym_m
+        with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
+            xq, M = self._pad_pool(xs)
+            with jax.enable_x64(True):
+                mu = _predict_mean_jit(self._xb, self._alpha_m,
+                                       np.int32(self._n), xq, self.ls,
+                                       self.signal)
+            return _fetch(mu)[:M] * self._ys_m + self._ym_m
 
     def score_ehvi(self, xs: np.ndarray, front_y: np.ndarray,
                    ref: np.ndarray) -> np.ndarray:
@@ -488,13 +508,14 @@ class JaxIncrementalGP:
         F = _pow2_small(len(front))
         pad = np.repeat([[ref[0], front[-1, 1]]], F - len(front), axis=0)
         fpad = np.vstack([front, pad])
-        xq, M = self._pad_pool(xs)
-        with jax.enable_x64(True):
-            s = _ehvi_jit(self._xb, self._alpha_m, np.int32(self._n), xq,
-                          jnp.asarray(fpad), jnp.asarray(ref),
-                          jnp.asarray(self._ym_m), jnp.asarray(self._ys_m),
-                          self.ls, self.signal)
-        return np.asarray(s)[:M]
+        with span("jx.gp.score_ehvi", cap=self._cap, rows=len(xs)):
+            xq, M = self._pad_pool(xs)
+            with jax.enable_x64(True):
+                s = _ehvi_jit(self._xb, self._alpha_m, np.int32(self._n), xq,
+                              jnp.asarray(fpad), jnp.asarray(ref),
+                              jnp.asarray(self._ym_m),
+                              jnp.asarray(self._ys_m), self.ls, self.signal)
+            return _fetch(s)[:M]
 
     def stats(self) -> dict:
         return {"n_active": self._n, "n_total": self._n_all,

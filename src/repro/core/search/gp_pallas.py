@@ -31,7 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.search.gp_jax import JaxIncrementalGP, _pow2_small
+from repro.core.search.gp_jax import JaxIncrementalGP, _fetch, _pow2_small
+from repro.core.tracing import span
 from repro.kernels import gp_ops
 
 
@@ -102,7 +103,7 @@ class PallasIncrementalGP(JaxIncrementalGP):
         self._n += m
         self.n_appends += 1
         self.n_pallas_appends += 1
-        if not bool(ok):
+        if not bool(_fetch(ok)):
             # degenerate block: same masked-refactor fallback as numpy/jnp
             self._refactor()
 
@@ -137,18 +138,19 @@ class PallasIncrementalGP(JaxIncrementalGP):
         ])
         ymd = np.stack([np.asarray(self._ym_m, float),
                         np.asarray(self._ys_m, float)])
-        xq, M = self._pad_pool(xs)
-        ls2, ils = self._ls_args()
-        with jax.enable_x64(True):
-            s = gp_ops.gp_fused_ehvi(
-                self._xb, self._alpha_m, np.int32(self._n), xq,
-                jnp.asarray(stair), jnp.asarray(ymd), ils,
-                ls2=ls2, signal=self.signal,
-                block=min(self.block, self._cap),
-                pool_block=min(self.pool_block, xq.shape[0]),
-                interpret=gp_ops._interpret())
-        self.n_pallas_scores += 1
-        return np.asarray(s)[:M]
+        with span("jx.gp.score_ehvi", cap=self._cap, rows=len(xs)):
+            xq, M = self._pad_pool(xs)
+            ls2, ils = self._ls_args()
+            with jax.enable_x64(True):
+                s = gp_ops.gp_fused_ehvi(
+                    self._xb, self._alpha_m, np.int32(self._n), xq,
+                    jnp.asarray(stair), jnp.asarray(ymd), ils,
+                    ls2=ls2, signal=self.signal,
+                    block=min(self.block, self._cap),
+                    pool_block=min(self.pool_block, xq.shape[0]),
+                    interpret=gp_ops._interpret())
+            self.n_pallas_scores += 1
+            return _fetch(s)[:M]
 
     def stats(self) -> dict:
         out = super().stats()

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig, SHAPES, get_arch
+from repro.core.tracing import span
 from repro.models.model import BuildFlags, Model
 from repro.parallel.sharding import ShardingPolicy
 from repro.train.optimizer import adafactor, adamw, cosine_schedule
@@ -50,48 +51,54 @@ def build_cell(arch: ArchConfig, shape: ShapeConfig, mesh,
                optimizer: Optional[str] = None,
                donate: bool = False,
                compile: bool = True) -> BuiltCell:
-    policy = ShardingPolicy(mesh, sp=flags.sp, fsdp=flags.fsdp)
-    model = Model(arch, flags, policy)
-    n_dev = mesh.size
-    meta: Dict[str, Any] = {"arch": arch.name, "shape": shape.name}
+    with span("jx.build.lower", kind=shape.kind):
+        policy = ShardingPolicy(mesh, sp=flags.sp, fsdp=flags.fsdp)
+        model = Model(arch, flags, policy)
+        n_dev = mesh.size
+        meta: Dict[str, Any] = {"arch": arch.name, "shape": shape.name}
 
-    if shape.kind == "train":
-        opt, opt_name = pick_optimizer(arch, optimizer)
-        meta["optimizer"] = opt_name
-        step = make_train_step(model, opt, tsc, policy=policy)
-        state_shapes = train_state_shapes(model, opt, tsc)
-        state_sh = _state_shardings(policy, state_shapes)
-        batch = model.input_specs(shape)
-        batch_sh = policy.batch_shardings(batch)
-        jfn = jax.jit(step, in_shardings=(state_sh, batch_sh),
-                      out_shardings=(state_sh, None),
-                      donate_argnums=(0,) if donate else ())
-        lowered = jfn.lower(state_shapes, batch)
-    elif shape.kind == "prefill":
-        batch = model.input_specs(shape)
-        batch_sh = policy.batch_shardings(batch)
-        params_shapes = model.init_shapes()
-        params_sh = policy.param_shardings(params_shapes)
-        jfn = jax.jit(model.prefill, in_shardings=(params_sh, batch_sh))
-        lowered = jfn.lower(params_shapes, batch)
-    elif shape.kind == "decode":
-        params_shapes = model.init_shapes()
-        params_sh = policy.param_shardings(params_shapes)
-        cache_shapes = jax.eval_shape(
-            lambda: model.empty_caches(shape.global_batch, shape.seq_len))
-        cache_sh = policy.cache_shardings(cache_shapes)
-        tokens = model.input_specs(shape)["tokens"]
-        tok_sh = policy.sharding(policy.batch_spec(tokens.shape))
-        pos = jax.ShapeDtypeStruct((), jnp.int32)
-        jfn = jax.jit(model.decode_step,
-                      in_shardings=(params_sh, tok_sh, cache_sh, policy.replicated()),
-                      out_shardings=(None, cache_sh),
-                      donate_argnums=(2,) if donate else ())
-        lowered = jfn.lower(params_shapes, tokens, cache_shapes, pos)
-    else:
-        raise ValueError(shape.kind)
+        if shape.kind == "train":
+            opt, opt_name = pick_optimizer(arch, optimizer)
+            meta["optimizer"] = opt_name
+            step = make_train_step(model, opt, tsc, policy=policy)
+            state_shapes = train_state_shapes(model, opt, tsc)
+            state_sh = _state_shardings(policy, state_shapes)
+            batch = model.input_specs(shape)
+            batch_sh = policy.batch_shardings(batch)
+            jfn = jax.jit(step, in_shardings=(state_sh, batch_sh),
+                          out_shardings=(state_sh, None),
+                          donate_argnums=(0,) if donate else ())
+            lowered = jfn.lower(state_shapes, batch)
+        elif shape.kind == "prefill":
+            batch = model.input_specs(shape)
+            batch_sh = policy.batch_shardings(batch)
+            params_shapes = model.init_shapes()
+            params_sh = policy.param_shardings(params_shapes)
+            jfn = jax.jit(model.prefill, in_shardings=(params_sh, batch_sh))
+            lowered = jfn.lower(params_shapes, batch)
+        elif shape.kind == "decode":
+            params_shapes = model.init_shapes()
+            params_sh = policy.param_shardings(params_shapes)
+            cache_shapes = jax.eval_shape(
+                lambda: model.empty_caches(shape.global_batch, shape.seq_len))
+            cache_sh = policy.cache_shardings(cache_shapes)
+            tokens = model.input_specs(shape)["tokens"]
+            tok_sh = policy.sharding(policy.batch_spec(tokens.shape))
+            pos = jax.ShapeDtypeStruct((), jnp.int32)
+            jfn = jax.jit(model.decode_step,
+                          in_shardings=(params_sh, tok_sh, cache_sh,
+                                        policy.replicated()),
+                          out_shardings=(None, cache_sh),
+                          donate_argnums=(2,) if donate else ())
+            lowered = jfn.lower(params_shapes, tokens, cache_shapes, pos)
+        else:
+            raise ValueError(shape.kind)
 
-    compiled = lowered.compile() if compile else None
+    compiled = None
+    if compile:
+        # a load from the persistent compile cache counts here too
+        with span("jx.build.compile", kind=shape.kind):
+            compiled = lowered.compile()
     return BuiltCell(shape.kind, lowered, compiled, n_dev, meta)
 
 

@@ -198,6 +198,7 @@ def make_build_fn(args, jc):
     import jax
 
     from repro.configs import SHAPES, get_arch, reduced
+    from repro.core.tracing import span
     from repro.launch.build import build_cell, build_generation
     from repro.launch.mesh import make_mesh_dp_tp
     from repro.roofline.analysis import summarize
@@ -222,23 +223,26 @@ def make_build_fn(args, jc):
                 arch, mesh, flags, batch=1,
                 prompt_len=args.prompt_len,
                 max_len=args.prompt_len + args.gen_tokens + 1)
-            pre = summarize(pre_cell.compiled, mesh.size)
-            dec = summarize(dec_cell.compiled, mesh.size)
-            pre.hbm_est_per_device = analytic_hbm_bytes_per_device(
-                arch, ShapeConfig("p", "prefill", args.prompt_len, 1),
-                flags, mesh.size, dp, tp)
-            dec.hbm_est_per_device = analytic_hbm_bytes_per_device(
-                arch, ShapeConfig("d", "decode",
-                                  args.prompt_len + args.gen_tokens + 1, 1),
-                flags, mesh.size, dp, tp)
+            with span("jx.build.analyze"):
+                pre = summarize(pre_cell.compiled, mesh.size)
+                dec = summarize(dec_cell.compiled, mesh.size)
+                pre.hbm_est_per_device = analytic_hbm_bytes_per_device(
+                    arch, ShapeConfig("p", "prefill", args.prompt_len, 1),
+                    flags, mesh.size, dp, tp)
+                dec.hbm_est_per_device = analytic_hbm_bytes_per_device(
+                    arch, ShapeConfig("d", "decode",
+                                      args.prompt_len + args.gen_tokens + 1,
+                                      1),
+                    flags, mesh.size, dp, tp)
             return pre, {"decode_artifact": dec,
                          "n_decode_tokens": args.gen_tokens}
         shape = SHAPES[tc.shape]
         cell = build_cell(arch, shape, mesh, flags)
-        art = summarize(cell.compiled, mesh.size)
-        art.hbm_est_per_device = analytic_hbm_bytes_per_device(
-            arch, shape, flags, mesh.size, dp, tp,
-            optimizer=cell.meta.get("optimizer", "adamw"))
+        with span("jx.build.analyze"):
+            art = summarize(cell.compiled, mesh.size)
+            art.hbm_est_per_device = analytic_hbm_bytes_per_device(
+                arch, shape, flags, mesh.size, dp, tp,
+                optimizer=cell.meta.get("optimizer", "adamw"))
         return art, {}
 
     return build
