@@ -18,6 +18,13 @@ buffers and runs every hot step as one jitted call:
   ``score_ehvi`` each run kernel GEMM + solve (+ the EHVI staircase sweep)
   over the whole candidate pool in one device call; pools are row-padded to
   powers of two so retraces stay bounded.
+* **Pool posterior reuse** — the posterior variance depends on the factor
+  and the pool, not on the targets.  ``predict`` / ``predict_multi`` keep
+  the padded device pool and the host sd of their last full call; a later
+  call on equal rows (``np.array_equal``) against the same factor and
+  hyperparameters runs only the mean, so ParEGO's picks over one pool pay
+  for one pool copy and one variance product per ask.  Every reassignment
+  of the factor bumps ``_version``, which drops the reuse.
 * **Inducing points (subset-of-data)** — every observation lands in a
   host-side archive, but past ``inducing_threshold`` active points the
   factor is periodically *thinned* back to an evenly-strided subset of the
@@ -199,7 +206,7 @@ def _predict_jit(xb, lib, alpha, n, xq, ls, signal):
     mu = ks @ alpha                                     # (P, J) normalized
     v = lib @ ks.T
     var = jnp.clip(signal - jnp.sum(v * v, axis=0), 1e-9, None)
-    return mu, var
+    return jnp.concatenate([mu, var[:, None]], axis=1)  # one fetch: (P, J+1)
 
 
 @jax.jit
@@ -262,6 +269,13 @@ class JaxIncrementalGP:
         self.n_refactors = 0
         self.n_thins = 0
         self.n_rethins = 0      # thins that reused the buffers in place
+        self.n_predicts = 0
+        self.n_predict_reuses = 0   # predicts that reused the pool's sd
+        # bumped whenever the factor (_xb, _lb, _lib, _n) is reassigned;
+        # _pool holds (_pool_key(), rows, device pool, sd) of the last full
+        # predict
+        self._version = 0
+        self._pool = None
         # fit state (single- and multi-target kept separate, like numpy)
         self._alpha1 = self._ym = self._ys = None
         self._alpha_m = self._ym_m = self._ys_m = None
@@ -289,6 +303,7 @@ class JaxIncrementalGP:
                 lib = lib.at[:n, :n].set(self._lib[:n, :n])
         self._xb, self._lb, self._lib = xb, lb, lib
         self._cap, self._dim = cap, dim
+        self._version += 1
         act = np.zeros(cap, np.int64)
         act[:self._n] = self._active_idx[:self._n]
         self._active_idx = act
@@ -336,6 +351,7 @@ class JaxIncrementalGP:
                 self.ls, self.noise, self.signal)
         self._active_idx[self._n:self._n + m] = idx
         self._n += m
+        self._version += 1
         self.n_appends += 1
         if not bool(_fetch(ok)):
             # degenerate block (duplicated rows beyond the noise jitter):
@@ -347,6 +363,7 @@ class JaxIncrementalGP:
                 jax.enable_x64(True):
             self._lb, self._lib = _refactor_jit(
                 self._xb, np.int32(self._n), self.ls, self.noise, self.signal)
+        self._version += 1
         self.n_refactors += 1
 
     def _thin(self) -> None:
@@ -379,6 +396,7 @@ class JaxIncrementalGP:
                     self._xb = (jnp.zeros((self._cap, d), jnp.float64)
                                 .at[:m, :].set(jnp.asarray(xa)))
             self._n = m
+            self._version += 1
             self._active_idx[:m] = sel
             self._refactor()
         self.n_thins += 1
@@ -398,6 +416,7 @@ class JaxIncrementalGP:
         """Reset and bulk-load (equivalence/refit entry point)."""
         self._n = 0
         self._n_all = 0
+        self._version += 1
         return self.observe(x)
 
     # -- fits -----------------------------------------------------------------
@@ -456,27 +475,49 @@ class JaxIncrementalGP:
             xq = jnp.asarray(xq)
         return xq, len(xs)
 
-    def predict(self, xs: np.ndarray):
-        with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
+    def _pool_key(self) -> tuple:
+        """What the pool's sd depends on besides its rows."""
+        return (self._version, np.ndim(self.ls),
+                np.asarray(self.ls, float).tobytes(), self.noise, self.signal)
+
+    def _posterior(self, xs: np.ndarray, alpha):
+        """Normalised mean (M, J) and sd (M,) over the rows ``xs``.
+
+        Rows equal to the last full predict's, against the same factor and
+        hyperparameters, run the mean alone on that call's device pool and
+        take its sd; any other rows run mean and variance in one program
+        and come back in one fetch."""
+        xs = np.atleast_2d(np.asarray(xs, float))
+        key = self._pool_key()
+        pool = self._pool
+        reuse = (pool is not None and pool[0] == key
+                 and np.array_equal(pool[1], xs))
+        self.n_predicts += 1
+        self.n_predict_reuses += reuse
+        with span("jx.gp.predict", cap=self._cap, rows=len(xs),
+                  reuse=int(reuse)):
+            if reuse:
+                xq, sd = pool[2], pool[3]
+                with jax.enable_x64(True):
+                    mu = _predict_mean_jit(self._xb, alpha, np.int32(self._n),
+                                           xq, self.ls, self.signal)
+                return _fetch(mu)[:len(xs)], sd
             xq, M = self._pad_pool(xs)
             with jax.enable_x64(True):
-                mu, var = _predict_jit(self._xb, self._lib, self._alpha1,
-                                       np.int32(self._n), xq, self.ls,
-                                       self.signal)
-            mu = _fetch(mu)[:M, 0]
-            sig = np.sqrt(_fetch(var)[:M])
-        return mu * self._ys + self._ym, sig * self._ys
+                out = _fetch(_predict_jit(self._xb, self._lib, alpha,
+                                          np.int32(self._n), xq, self.ls,
+                                          self.signal))[:M]
+            sd = np.sqrt(out[:, -1])
+            self._pool = (key, xs.copy(), xq, sd)
+            return out[:, :-1], sd
+
+    def predict(self, xs: np.ndarray):
+        mu, sd = self._posterior(xs, self._alpha1)
+        return mu[:, 0] * self._ys + self._ym, sd * self._ys
 
     def predict_multi(self, xs: np.ndarray):
-        with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
-            xq, M = self._pad_pool(xs)
-            with jax.enable_x64(True):
-                mu, var = _predict_jit(self._xb, self._lib, self._alpha_m,
-                                       np.int32(self._n), xq, self.ls,
-                                       self.signal)
-            mu = _fetch(mu)[:M] * self._ys_m + self._ym_m
-            sig = np.sqrt(_fetch(var)[:M])[:, None] * self._ys_m
-        return mu, sig
+        mu, sd = self._posterior(xs, self._alpha_m)
+        return mu * self._ys_m + self._ym_m, sd[:, None] * self._ys_m
 
     def predict_mean_multi(self, xs: np.ndarray) -> np.ndarray:
         with span("jx.gp.predict", cap=self._cap, rows=len(xs)):
@@ -521,7 +562,8 @@ class JaxIncrementalGP:
         return {"n_active": self._n, "n_total": self._n_all,
                 "capacity": self._cap, "appends": self.n_appends,
                 "refactors": self.n_refactors, "thins": self.n_thins,
-                "rethins": self.n_rethins}
+                "rethins": self.n_rethins, "predicts": self.n_predicts,
+                "predict_reuses": self.n_predict_reuses}
 
     # -- durable state ---------------------------------------------------------
     def state_dict(self) -> dict:
@@ -580,6 +622,7 @@ class JaxIncrementalGP:
         self.n_refactors = state["n_refactors"]
         self.n_thins = state["n_thins"]
         self.n_rethins = state["n_rethins"]
+        self._version += 1
         self._alpha1 = self._ym = self._ys = None
         self._alpha_m = self._ym_m = self._ys_m = None
         return self

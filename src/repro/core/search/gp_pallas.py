@@ -101,6 +101,7 @@ class PallasIncrementalGP(JaxIncrementalGP):
                 interpret=gp_ops._interpret())
         self._active_idx[self._n:self._n + m] = idx
         self._n += m
+        self._version += 1
         self.n_appends += 1
         self.n_pallas_appends += 1
         if not bool(_fetch(ok)):

@@ -2,6 +2,7 @@
 reference across doubling boundaries, float64 regression (no silent float32
 and no global x64 leak), the fused EHVI device sweep, subset-of-data
 inducing points (engagement + error bound), degenerate-append fallback,
+the pool posterior's reuse across predicts and what drops it,
 pick-sequence equality through BayesOpt/PAL, and the hyperparameter refresh
 schedule riding the device buffers."""
 import numpy as np
@@ -9,7 +10,9 @@ import pytest
 
 from repro.core.search.bayesopt import (BayesOpt, GP, IncrementalGP, PAL,
                                         ehvi_improvements)
+from repro.core.search import gp_jax
 from repro.core.search.gp_jax import JaxIncrementalGP
+from repro.core.search.gp_pallas import PallasIncrementalGP
 from repro.core.space import tpu_pod_space
 
 
@@ -156,6 +159,94 @@ def test_inducing_error_bounded_on_smooth_function():
 
 
 # ---------------------------------------------------------------------------
+# pool posterior reuse: the sd of the last full predict, for equal rows
+# against an unchanged factor
+# ---------------------------------------------------------------------------
+
+
+def _same_pool(gp, x, pool, rng):
+    return pool
+
+
+def _observe(gp, x, pool, rng):
+    gp.observe(rng.random((2, x.shape[1])))
+    return pool
+
+
+def _set_lengthscale(gp, x, pool, rng):
+    gp.set_lengthscale(0.45)
+    return pool
+
+
+def _thin(gp, x, pool, rng):
+    gp._thin()
+    return pool
+
+
+def _load_state(gp, x, pool, rng):
+    gp.load_state(gp.state_dict())
+    return pool
+
+
+def _signal(gp, x, pool, rng):
+    gp.signal = 1.5
+    return pool
+
+
+def _noise(gp, x, pool, rng):
+    gp.noise = 2e-3
+    return pool
+
+
+def _other_pool(gp, x, pool, rng):
+    return rng.random(pool.shape)
+
+
+def _other_shape(gp, x, pool, rng):
+    return pool[:-1]
+
+
+@pytest.mark.parametrize("kind", ["jax", "pallas"])
+@pytest.mark.parametrize("change", [
+    _same_pool, _observe, _set_lengthscale, _thin, _load_state, _signal,
+    _noise, _other_pool, _other_shape], ids=lambda f: f.__name__.strip("_"))
+def test_pool_posterior_reused_only_for_the_same_rows_and_factor(kind,
+                                                                 change):
+    """A second predict on the same pool with new targets runs the mean
+    alone and equals a fresh GP's full predict; after any change of the
+    factor, the hyperparameters or the rows it runs in full again.  The
+    pallas GP changes the factor through its own ``_append_active``."""
+    rng = np.random.default_rng(7)
+    make = {"jax": JaxIncrementalGP, "pallas": PallasIncrementalGP}[kind]
+    x = rng.random((28, 4))
+    pool = rng.random((20, 4))
+    gp = make(inducing_threshold=24).fit_x(x)
+    gp.fit_y(rng.random(len(x))).predict(pool)
+    gp.fit_y_multi(rng.random((len(x), 2))).predict_multi(pool)
+    before = gp.stats()
+    assert before["predicts"] == 2 and before["predict_reuses"] == 1
+    pool = change(gp, x, pool, rng)
+    y = rng.random(gp.n_total)
+    mu, sd = gp.fit_y(y).predict(pool)
+    after = gp.stats()
+    reused = change is _same_pool
+    assert after["predicts"] == before["predicts"] + 1
+    assert after["predict_reuses"] == before["predict_reuses"] + reused
+    fresh = make(lengthscale=gp.ls, noise=gp.noise, signal=gp.signal,
+                 inducing_threshold=24)
+    fresh.fit_x(gp._ax[gp._active_idx[:len(gp)]])
+    mu_f, sd_f = fresh.fit_y(gp._active_targets(y)).predict(pool)
+    assert fresh.stats()["predict_reuses"] == 0
+    if change in (_signal, _noise):
+        # the factor was built with the old hyperparameters: only the miss
+        # is asserted, a fresh factor would differ
+        return
+    np.testing.assert_allclose(mu, mu_f, rtol=1e-12,
+                               atol=1e-12 * np.abs(mu_f).max())
+    np.testing.assert_allclose(sd, sd_f, rtol=1e-12)
+
+
+# ---------------------------------------------------------------------------
 # pick-sequence equality through the searchers
 # ---------------------------------------------------------------------------
 
@@ -173,6 +264,45 @@ def test_bayesopt_jax_picks_match_incremental():
             seq.append(c)
         seqs[mode] = seq
     assert seqs["jax"] == seqs["incremental"]
+
+
+def test_bayesopt_parego_jax_picks_match_incremental():
+    space = tpu_pod_space(n_chips=256)
+    seqs = {}
+    for mode in ("incremental", "jax"):
+        algo = BayesOpt(space, seed=5, n_init=8, pool_size=64,
+                        strategy="parego", gp_mode=mode)
+        seq = []
+        for _ in range(8):
+            batch = algo.ask(4)
+            for c in batch:
+                algo.tell(c, _toy_objectives(space, c))
+            seq += batch
+        seqs[mode] = seq
+    assert seqs["jax"] == seqs["incremental"]
+
+
+def test_parego_ask_fetches_once_per_pick_and_reuses_the_pool(monkeypatch):
+    """At steady state a ParEGO ask of 4 copies one append flag and one
+    posterior per pick to the host, and three picks reuse the first's
+    pool sd."""
+    space = tpu_pod_space(n_chips=256)
+    algo = BayesOpt(space, seed=5, n_init=8, pool_size=64,
+                    strategy="parego", gp_mode="jax")
+    for _ in range(4):
+        for c in algo.ask(4):
+            algo.tell(c, _toy_objectives(space, c))
+    fetched = []
+    fetch = gp_jax._fetch
+    monkeypatch.setattr(gp_jax, "_fetch",
+                        lambda x: fetched.append(x.shape) or fetch(x))
+    before = algo._gp.stats()
+    batch = algo.ask(4)
+    after = algo._gp.stats()
+    assert len(batch) == 4
+    assert len(fetched) == 5, fetched
+    assert after["predicts"] - before["predicts"] == 4
+    assert after["predict_reuses"] - before["predict_reuses"] == 3
 
 
 def test_pal_jax_picks_match_incremental():
