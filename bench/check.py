@@ -15,8 +15,8 @@ the plain references of ``bench/reference.py``:
 * ``param_bytes_gap`` build: for every build, the relative gap between the
   prefill program's argument bytes per device and the parameter and input
   bytes one device holds, counted from the configuration file: a build
-  that leaves out layers, shards otherwise than the board's chips, or
-  stores another type than the stated bfloat16 moves it.
+  that leaves out layers, holds other experts, shards otherwise than the
+  board's chips, or stores another type than the stated bfloat16 moves it.
 * ``failed``          evaluations that came back not ``ok``; exact.
 
 Each number has its limit in the traffic or configuration file.  With

@@ -34,9 +34,15 @@ def fit_y_flops(cap: int, targets: int = 1) -> int:
     return 4 * cap * cap * targets
 
 
+def predict_mean_flops(cap: int, pool: int, dim: int, targets: int = 1) -> int:
+    """Posterior mean alone over a ``pool``-row (padded) batch: the kernel
+    block and ks @ alpha, all a predict runs where it reuses the last full
+    predict's pool and variance."""
+    return kernel_flops(pool, cap, dim) + 2 * pool * cap * targets
+
+
 def predict_flops(cap: int, pool: int, dim: int, targets: int = 1) -> int:
     """Posterior mean and variance over a ``pool``-row (padded) batch."""
-    return (kernel_flops(pool, cap, dim)
-            + 2 * pool * cap * targets   # ks @ alpha
+    return (predict_mean_flops(cap, pool, dim, targets)
             + 2 * cap * cap * pool       # v = lib @ ks.T
             + 2 * cap * pool)            # column sums of v * v

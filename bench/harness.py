@@ -43,9 +43,29 @@ ARCH_FIELDS = {
     "num_hidden_layers": "n_layers", "hidden_size": "d_model",
     "vocab_size": "vocab_size", "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
-    "state_size": "ssm_state", "expand": "ssm_expand",
-    "conv_kernel": "ssm_conv", "tie_word_embeddings": "tie_embeddings",
+    "sliding_window": "sliding_window",
+    "state_size": "ssm_state", "ssm_state_size": "ssm_state",
+    "mamba_head_dim": "ssm_head_dim", "expand": "ssm_expand",
+    "conv_kernel": "ssm_conv",
+    "n_routed_experts": "n_experts", "num_experts": "n_experts",
+    "router_dtype": "router_dtype",
+    "num_experts_per_tok": "moe_top_k", "moe_intermediate_size": "moe_d_ff",
+    "n_shared_experts": "n_shared_experts",
+    "first_k_dense_replace": "first_k_dense",
+    "tie_word_embeddings": "tie_embeddings",
 }
+# what the program runs where its ArchConfig has no field of that name
+PROGRAM_IMPLIED = {
+    "router_dtype": lambda arch: "float32",
+    "ffn_act": lambda arch: "silu",
+}
+# every other key a configuration file's model may state; a key outside
+# these and ARCH_FIELDS is counted by no reference and held by no check
+MODEL_KEYS = {"kind", "layers", "head_dim", "pad_vocab_size_multiple",
+              "hidden_act", "mlp_hidden_act"}
+# the configuration file's mixer names -> the program's LayerSpec mixers
+MIXER_NAMES = {"attention": "attn", "attention_window": "attn_local",
+               "mamba2": "mamba"}
 WARMUP_SWEEP = 999          # search seed offset of the set-up sweep
 # a traced run profiles the first seconds of its window only: the device
 # tracer's buffer fills in about 20 s of the warm cell and drops what follows
@@ -214,10 +234,18 @@ class SearchRecorder:
             rec.gp_flops.append((time.monotonic(), counts.fit_y_flops(cap())))
             return fit_y(y)
 
+        def reuses():
+            stats = getattr(gp, "stats", None)
+            return stats().get("predict_reuses", 0) if stats else 0
+
         def predict_rec(xs):
+            before = reuses()
             mu, sig = predict(xs)
             xs = np.atleast_2d(xs)
-            rec.gp_flops.append((time.monotonic(), counts.predict_flops(
+            # a reuse runs the mean alone, on the last full predict's pool
+            count = (counts.predict_mean_flops if reuses() > before
+                     else counts.predict_flops)
+            rec.gp_flops.append((time.monotonic(), count(
                 cap(), pow2_small(len(xs)), xs.shape[1])))
             call = GPCall(sweep.index, len(state["y"]), state["y"], xs,
                           mu, sig)
@@ -274,13 +302,46 @@ def artifact_counts(art) -> dict:
 
 def arch_fields(model: dict) -> Dict[str, object]:
     """The program's ``ArchConfig`` fields a configuration file's model sizes
-    set, by ``ARCH_FIELDS``; the vocabulary as the rows the model holds."""
+    set, by ``ARCH_FIELDS``; the vocabulary as the rows the model holds, and
+    the feed-forward activation where the file states one."""
     fields = dict(ARCH_FIELDS, head_dim=("ssm_head_dim"
                                          if model["kind"] == "mamba2"
                                          else "head_dim"))
     out = {field: model[key] for key, field in fields.items() if key in model}
     if "vocab_size" in model:
         out["vocab_size"] = reference.vocab_rows(model)
+    if "mlp_hidden_act" in model or "hidden_act" in model:
+        out["ffn_act"] = reference.ffn_act(model)
+    return out
+
+
+def program_value(arch, field: str):
+    """An ``ArchConfig`` field or property, or what the program implies
+    where it has neither."""
+    if hasattr(arch, field):
+        return getattr(arch, field)
+    return PROGRAM_IMPLIED[field](arch)
+
+
+def size_mismatches(model: dict, arch) -> List[str]:
+    """Where the program's architecture departs from the configuration
+    file's sizes and layer list; empty where it runs what the file states."""
+    out = [f"the configuration file states {field}={value!r}, the program "
+           f"runs {field}={program_value(arch, field)!r}"
+           for field, value in arch_fields(model).items()
+           if program_value(arch, field) != value]
+    unknown = sorted(set(model) - set(ARCH_FIELDS) - MODEL_KEYS)
+    if unknown:
+        out.append(f"the configuration file states {', '.join(unknown)}, "
+                   f"which the reference does not count")
+    want = [(MIXER_NAMES.get(m, m), f)
+            for m, f in reference.layer_kinds(model)]
+    got = [(s.mixer, s.ffn) for s in arch.layer_specs()]
+    for i, (w, g) in enumerate(zip(want, got)):
+        if w != g:
+            out.append(f"the configuration file states layer {i} as "
+                       f"{w}, the program runs {g}")
+            break
     return out
 
 
@@ -290,14 +351,17 @@ def workload_arch(cfg: dict) -> str:
     The program's registered architecture where it holds the file's sizes;
     otherwise (a cut in depth, a tied head, a padded vocabulary) those sizes
     registered through the program's own ``register`` as a workload named
-    after the configuration, the way each of its config modules adds one."""
+    after the configuration, the way each of its config modules adds one.
+    Sizes that are no field of ``ArchConfig`` are left to ``check_sizes``."""
     from repro.configs import get_arch
     from repro.configs.base import register
 
     base = get_arch(cfg["arch"])
     fields = arch_fields(cfg["model"])
-    if all(getattr(base, f) == v for f, v in fields.items()):
+    if all(program_value(base, f) == v for f, v in fields.items()):
         return base.name
+    settable = {f.name for f in dataclasses.fields(base)} & set(fields)
+    fields = {f: fields[f] for f in settable}
     name = cfg["name"] + ".configured"
     try:
         arch = get_arch(name)
@@ -354,12 +418,9 @@ class Cell:
 
     def check_sizes(self):
         """The configuration file holds the sizes the program runs."""
-        for field, value in arch_fields(self.cfg["model"]).items():
-            if getattr(self.arch, field) != value:
-                raise ValueError(
-                    f"{self.cfg['name']}: the configuration file states "
-                    f"{field}={value}, the program runs "
-                    f"{field}={getattr(self.arch, field)}")
+        bad = size_mismatches(self.cfg["model"], self.arch)
+        if bad:
+            raise ValueError(f"{self.cfg['name']}: " + "; ".join(bad))
 
     def all_configs(self) -> List[dict]:
         names = [n for n, _ in self.space_values]
@@ -488,17 +549,52 @@ def hv_time(sweep: Sweep, front: dict, deadline: float,
     return None
 
 
+def finished_sweeps(rec: Recorder) -> List[Sweep]:
+    """The sweeps that ended inside the window."""
+    return [s for s in rec.sweeps
+            if s.t_end is not None and s.t_end <= rec.deadline]
+
+
 def end_to_end(rec: Recorder, front: dict, seconds: float) -> Dict[str, float]:
+    """The window's end-to-end numbers: ``ok`` evaluations told per second;
+    ``sweep_s``, the window's wall seconds from its start to the end of
+    its last finished sweep over the number of finished sweeps, so a
+    fleet's start and teardown between sweeps count as well; ``hv95_s``,
+    the mean of ``hv_time`` over the sweeps; and the 95th percentile of
+    ask-to-tell latency."""
     done = [t for s in rec.sweeps for t, _ in s.tells if t <= rec.deadline]
     lat = [d for t, d in rec.latencies if t <= rec.deadline]
     hv = [h for h in (hv_time(s, front, rec.deadline) for s in rec.sweeps)
           if h is not None]
+    swept = finished_sweeps(rec)
     out = {"evals_per_s": len(done) / seconds}
+    if swept:
+        out["sweep_s"] = (max(s.t_end for s in swept) - rec.t0) / len(swept)
     if hv:
         out["hv95_s"] = float(np.mean(hv))
     if lat:
         out["eval_p95_ms"] = float(np.percentile(lat, 95)) * 1e3
     return out
+
+
+def log_sweeps(rec: Recorder, front: dict) -> None:
+    """Each sweep of the window: its seconds, its time to 95% of the
+    reference hypervolume, and the seconds of each build it ran; then the
+    median seconds of the finished sweeps, a diagnostic beside ``sweep_s``
+    that leaves out the time between sweeps."""
+    for s in rec.sweeps:
+        end = rec.t1 if s.t_end is None else s.t_end
+        builds = [round(e - b, 3) for b, e in rec.builds
+                  if s.t_start <= b < end]
+        hv = hv_time(s, front, rec.deadline)
+        log(f"sweep {s.index}: {end - s.t_start:.3f}s"
+            f"{'' if s.t_end else ' (closed)'}, hv95 "
+            f"{'-' if hv is None else f'{hv:.3f}s'}, {len(s.tells)} told, "
+            f"builds {builds}")
+    swept = finished_sweeps(rec)
+    if swept:
+        med = float(np.median([s.t_end - s.t_start for s in swept]))
+        log(f"median finished sweep {med!r}s over {len(swept)}")
 
 
 def memory_peak_bytes(n: int) -> Optional[int]:
@@ -536,12 +632,19 @@ def log(msg: str) -> None:
 def set_up(spec: Spec, workload: str, seed: int, cache_root: str):
     """Build the cell, measure the whole space for the reference front and
     run one sweep that warms every program the window's sweeps use."""
+    t0 = time.monotonic()
     cell = Cell(spec, workload, cache_root)
+    t1 = time.monotonic()
     front = reference_front(cell)
+    t2 = time.monotonic()
     warm = Recorder(deadline=math.inf, traced=False)
     cell.builds.rec = warm
     run_sweep(cell, warm, WARMUP_SWEEP, seed * 1000 + WARMUP_SWEEP, set())
     cell.builds.rec = None
+    built = sum(e - s for s, e in warm.builds)
+    log(f"set-up phases: cell {t1 - t0:.3f}s, reference front "
+        f"{t2 - t1:.3f}s, warm-up sweep {time.monotonic() - t2:.3f}s "
+        f"({len(warm.builds)} builds, {built:.3f}s)")
     return cell, front, warm
 
 
@@ -554,6 +657,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     spec = Spec(root)
     chips = int(spec.config(spec.cell(workload)["config"])["chips_per_board"])
     dev = devices(chips, require_chip)
+    log(f"devices found {time.monotonic() - t_process:.3f}s after the "
+        f"process start")
     peaks = reference.load_peaks(peaks_kind or dev["kind"])
     cache_root = tempfile.mkdtemp(prefix="bench-")
     try:
@@ -567,9 +672,11 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         mem = memory_peak_bytes(chips)
         e2e = end_to_end(rec, front, seconds)
         e2e["setup_s"] = setup_s
+        log("end to end: " + ", ".join(f"{k} {v!r}" for k, v in e2e.items()))
         log(f"window {rec.t1 - rec.t0:.3f}s: {len(rec.sweeps)} sweeps, "
             f"{sum(len(s.tells) for s in rec.sweeps)} evaluations told, "
             f"{len(rec.builds)} builds")
+        log_sweeps(rec, front)
         verdict = check.run_checks(cell, rec, peaks)
         out = {"correct": verdict["correct"],
                "attempted": verdict["attempted"],

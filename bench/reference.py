@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -120,31 +120,95 @@ def vocab_rows(model: dict) -> int:
     return -(-int(model["vocab_size"]) // m) * m
 
 
+# the configuration file's names for a layer's mixer and feed-forward part
+MIXERS = ("attention", "attention_window", "mamba2")
+FFNS = ("dense", "moe", "none")
+DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
+
+
+def layer_kinds(model: dict) -> List[Tuple[str, str]]:
+    """``(mixer, ffn)`` of each layer, as the file's ``layers`` list gives
+    them, or else by ``kind``: ``transformer`` is attention and a dense FFN
+    on every layer, ``mamba2`` a Mamba-2 mixer alone.  The first
+    ``first_k_dense_replace`` layers take a dense FFN where they name
+    ``moe``."""
+    n = model["num_hidden_layers"]
+    if "layers" in model:
+        kinds = [(layer["mixer"], layer["ffn"]) for layer in model["layers"]]
+    elif model["kind"] == "mamba2":
+        kinds = [("mamba2", "none")] * n
+    elif model["kind"] == "transformer":
+        kinds = [("attention", "dense")] * n
+    else:
+        raise ValueError(f"kind {model['kind']!r} needs a layers list")
+    if len(kinds) != n:
+        raise ValueError(f"{len(kinds)} layers listed, "
+                         f"num_hidden_layers is {n}")
+    for mixer, ffn in kinds:
+        if mixer not in MIXERS or ffn not in FFNS:
+            raise ValueError(f"unknown layer kind ({mixer!r}, {ffn!r})")
+    k = model.get("first_k_dense_replace", 0)
+    return [(m, "dense" if f == "moe" and i < k else f)
+            for i, (m, f) in enumerate(kinds)]
+
+
+def ffn_act(model: dict) -> str:
+    return model.get("mlp_hidden_act", model.get("hidden_act", "silu"))
+
+
+def ffn_matrix_params(model: dict, width: int) -> int:
+    """One gated feed-forward block of ``width``: gate, up and down."""
+    return 3 * model["hidden_size"] * width
+
+
+def mamba_sizes(model: dict) -> Dict[str, int]:
+    """Inner width (``expand`` x the hidden size), state, heads and
+    convolution of a Mamba-2 mixer with one group of B/C projections."""
+    head = model.get("mamba_head_dim", model.get("head_dim"))
+    di = model["expand"] * model["hidden_size"]
+    n = model.get("ssm_state_size", model.get("state_size"))
+    return {"di": di, "n": n, "h": di // head, "conv": model["conv_kernel"]}
+
+
 def param_bytes(model: dict, tp: int, bytes_per_param: float = 2.0) -> float:
     """Parameter bytes one device of a ``tp``-way board holds: matrices split
-    ``tp`` ways, vectors whole.  ``model`` holds the configuration file's
-    sizes; the mixer's small float32 vectors (Mamba-2's A_log, D and dt bias)
-    keep 4 bytes whatever ``bytes_per_param`` is."""
+    ``tp`` ways, vectors whole, counted layer by layer (``layer_kinds``).
+    ``model`` holds the configuration file's sizes.  The mixer's small
+    float32 vectors (Mamba-2's A_log, D and dt bias) keep 4 bytes whatever
+    ``bytes_per_param`` is, and an MoE router keeps the bytes of its
+    ``router_dtype`` (float32 unless the file states another), whole on
+    each device; the shared experts are ``n_shared_experts`` of
+    ``moe_intermediate_size``."""
     d, v = model["hidden_size"], vocab_rows(model)
-    mats, vecs, f32 = 0, 0, 0
-    for _ in range(model["num_hidden_layers"]):
-        if model["kind"] == "mamba2":
-            di = model["expand"] * d
-            n, conv = model["state_size"], model["conv_kernel"]
-            h = di // model["head_dim"]
-            mats += 2 * d * di + 2 * d * n + d * h + di * d
-            mats += (di + 2 * n) * conv
-            vecs += di + 2 * n + di + d        # conv biases, gated norm, norm
-            f32 += 3 * h
-        else:
+    mats, vecs, whole = 0, 0, 0            # whole: bytes at their own type
+    for mixer, ffn in layer_kinds(model):
+        if mixer in ("attention", "attention_window"):
             hq, hkv, dh = (model["num_attention_heads"],
                            model["num_key_value_heads"], model["head_dim"])
             mats += 2 * d * hq * dh + 2 * d * hkv * dh
-            mats += 3 * d * model["intermediate_size"]
-            vecs += 2 * d
+            vecs += d                          # norm
+        elif mixer == "mamba2":
+            m = mamba_sizes(model)
+            di, h, bc = m["di"], m["h"], 2 * m["n"]
+            mats += d * (2 * di + bc + h) + di * d
+            mats += (di + bc) * m["conv"]
+            vecs += di + bc + di + d           # conv biases, gated norm, norm
+            whole += 4 * 3 * h
+        if ffn == "dense":
+            mats += ffn_matrix_params(model, model["intermediate_size"])
+            vecs += d
+        elif ffn == "moe":
+            f = model["moe_intermediate_size"]
+            experts = model.get("n_routed_experts", model.get("num_experts"))
+            shared = model.get("n_shared_experts", 0) * f
+            mats += (experts * ffn_matrix_params(model, f)
+                     + ffn_matrix_params(model, shared))
+            vecs += d
+            whole += (d * experts
+                      * DTYPE_BYTES[model.get("router_dtype", "float32")])
     vecs += d                                  # final norm
     mats += v * d * (1 if model["tie_word_embeddings"] else 2)
-    return (mats / tp + vecs) * bytes_per_param + 4 * f32
+    return (mats / tp + vecs) * bytes_per_param + whole
 
 
 def prefill_input_bytes(batch: int, prompt: int) -> int:

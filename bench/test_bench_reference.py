@@ -37,6 +37,10 @@ def test_gp_counts_by_hand():
     # the (cap, cap) x (cap, pool) product is about 1.07 GFLOP of it
     assert 2 * cap * cap * pool == 1_073_741_824
     assert counts.fit_y_flops(cap) == 4 * cap * cap
+    # a predict that reuses the last full one's pool runs the mean alone
+    assert counts.predict_mean_flops(cap, pool, dim) == kern + 2 * pool * cap
+    assert counts.predict_flops(cap, pool, dim) - counts.predict_mean_flops(
+        cap, pool, dim) == 2 * cap * cap * pool + 2 * cap * pool
     b = 4
     assert counts.append_flops(cap, b, dim) == (
         counts.kernel_flops(cap, b, dim) + counts.kernel_flops(b, b, dim)
@@ -120,21 +124,105 @@ def test_roofline_matches_the_programs_measures(n_chips):
     assert worst32 > 1e-9
 
 
-@pytest.mark.parametrize("config", ["mamba2-780m", "yi-9b.24l", "yi-9b"])
+# the program's registered layer mixes at their sizes, as configuration
+# files state them: routed and shared experts with a leading dense layer,
+# Mamba-2 and attention interleaved with MoE every other layer, and
+# windowed attention five layers of six
+LAYER_MIXES = {
+    "deepseek-moe-16b": {
+        "kind": "transformer", "num_hidden_layers": 28, "hidden_size": 2048,
+        "num_attention_heads": 16, "num_key_value_heads": 16,
+        "head_dim": 128, "intermediate_size": 10944, "vocab_size": 102400,
+        "tie_word_embeddings": False, "hidden_act": "silu",
+        "n_routed_experts": 64, "n_shared_experts": 2,
+        "num_experts_per_tok": 6, "moe_intermediate_size": 1408,
+        "first_k_dense_replace": 1, "router_dtype": "float32",
+        "layers": [{"mixer": "attention", "ffn": "moe"}] * 28},
+    "jamba-v0.1-52b": {
+        "kind": "hybrid", "num_hidden_layers": 32, "hidden_size": 4096,
+        "num_attention_heads": 32, "num_key_value_heads": 8, "head_dim": 128,
+        "intermediate_size": 14336, "vocab_size": 65536,
+        "tie_word_embeddings": False, "num_experts": 16,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 14336,
+        "router_dtype": "float32", "ssm_state_size": 16,
+        "mamba_head_dim": 64, "expand": 2, "conv_kernel": 4,
+        "layers": [{"mixer": "attention" if i % 8 == 4 else "mamba2",
+                    "ffn": "moe" if i % 2 else "dense"} for i in range(32)]},
+    "gemma3-27b": {
+        "kind": "transformer", "num_hidden_layers": 62, "hidden_size": 5376,
+        "num_attention_heads": 32, "num_key_value_heads": 16,
+        "head_dim": 128, "intermediate_size": 21504, "vocab_size": 262144,
+        "tie_word_embeddings": False, "sliding_window": 1024,
+        "layers": [{"mixer": "attention" if i % 6 == 5
+                    else "attention_window", "ffn": "dense"}
+                   for i in range(62)]},
+}
+
+
+@pytest.mark.parametrize("config", ["mamba2-780m", "yi-9b.24l", "yi-9b",
+                                    *LAYER_MIXES])
 def test_param_bytes_count_the_programs_parameters(config):
     """At the configured sizes, on the parameter shapes the program builds
     for the workload the harness explores."""
     import jax
 
-    from bench.harness import workload_arch
+    from bench.harness import size_mismatches, workload_arch
     from repro.configs import get_arch
     from repro.models import BuildFlags, Model
 
-    with open(os.path.join(HERE, "configs", config + ".json")) as f:
-        cfg = json.load(f)
+    if config in LAYER_MIXES:
+        cfg = {"name": config, "arch": config, "model": LAYER_MIXES[config]}
+        assert workload_arch(cfg) == config
+    else:
+        with open(os.path.join(HERE, "configs", config + ".json")) as f:
+            cfg = json.load(f)
     model = cfg["model"]
-    shapes = Model(get_arch(workload_arch(cfg)), BuildFlags()).init_shapes()
+    arch = get_arch(workload_arch(cfg))
+    assert size_mismatches(model, arch) == []
+    shapes = Model(arch, BuildFlags()).init_shapes()
     got = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
               for leaf in jax.tree_util.tree_leaves(shapes))
     assert reference.param_bytes(model, 1) == got
     assert reference.param_bytes(model, 1, 1.0) < 0.51 * got
+
+
+def test_param_bytes_by_hand_split_two_ways():
+    """Mamba-2 and attention mixers, a leading dense layer, routed and
+    shared experts with a bfloat16 router, and a layer with no FFN, split
+    two ways: matrices halved, vectors and the router whole."""
+    d, v = 16, 40
+    model = {"kind": "hybrid", "num_hidden_layers": 3, "hidden_size": d,
+             "vocab_size": v, "tie_word_embeddings": False,
+             "num_attention_heads": 2, "num_key_value_heads": 1,
+             "head_dim": 8, "mamba_head_dim": 4, "expand": 2,
+             "ssm_state_size": 5, "conv_kernel": 4,
+             "intermediate_size": 20, "n_routed_experts": 2,
+             "n_shared_experts": 1, "moe_intermediate_size": 6,
+             "router_dtype": "bfloat16", "first_k_dense_replace": 1,
+             "layers": [{"mixer": "attention", "ffn": "moe"},
+                        {"mixer": "mamba2", "ffn": "none"},
+                        {"mixer": "attention", "ffn": "moe"}]}
+    di, bc, h = 2 * d, 2 * 5, 2 * d // 4
+    mamba_mats = d * (2 * di + bc + h) + di * d + (di + bc) * 4
+    mamba_vecs = (di + bc) + di + d
+    attn_mats = 2 * d * 2 * 8 + 2 * d * 1 * 8
+    dense = 3 * d * 20                   # layer 0: the leading dense layer
+    moe = 2 * (3 * d * 6) + 3 * d * 6    # layer 2: two experts, the shared
+    mats = attn_mats + dense + mamba_mats + attn_mats + moe + 2 * v * d
+    vecs = d + d + mamba_vecs + d + d + d   # attn, FFN, mixer, attn, FFN, final
+    whole = 4 * 3 * h + d * 2 * 2        # A_log, D, dt bias; bf16 router
+    assert reference.layer_kinds(model)[0] == ("attention", "dense")
+    assert reference.param_bytes(model, 2) == (mats / 2 + vecs) * 2 + whole
+    assert reference.param_bytes(model, 2, 1.0) == mats / 2 + vecs + whole
+
+
+def test_a_layer_mix_without_its_layers_is_refused():
+    with pytest.raises(ValueError, match="layers"):
+        reference.layer_kinds({"kind": "hybrid", "num_hidden_layers": 2})
+    with pytest.raises(ValueError, match="3 layers"):
+        reference.layer_kinds({"kind": "hybrid", "num_hidden_layers": 2,
+                               "layers": [{"mixer": "mamba2",
+                                           "ffn": "none"}] * 3})
+    with pytest.raises(ValueError, match="unknown"):
+        reference.layer_kinds({"kind": "hybrid", "num_hidden_layers": 1,
+                               "layers": [{"mixer": "rwkv", "ffn": "none"}]})

@@ -81,7 +81,20 @@ def test_a_random_search_run_is_correct_and_reports_its_metrics(tiny):
     assert out["correct"], out["checks"]
     assert "gp_gap" not in out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
-    assert {"eval_p95_ms", "setup_s"} <= set(out["metrics"])
+    assert set(out["metrics"]) == {"sweep_s", "setup_s"}
+    assert 0 < out["metrics"]["sweep_s"]["value"] < 15.0
+
+
+def test_sweep_s_counts_the_time_between_sweeps():
+    """``sweep_s`` is the window up to its last finished sweep over the
+    finished sweeps: a slow teardown between sweeps counts, a sweep the
+    window closes does not."""
+    rec = harness.Recorder(deadline=20.0, traced=False)
+    rec.t0 = 0.0
+    rec.sweeps = [harness.Sweep(0, 0.0, 4.0), harness.Sweep(1, 7.0, 11.0),
+                  harness.Sweep(2, 11.5, 15.5), harness.Sweep(3, 16.0)]
+    out = harness.end_to_end(rec, {"hv": 1.0, "ref_point": None}, 20.0)
+    assert out["sweep_s"] == 15.5 / 3
 
 
 def test_a_configuration_that_departs_from_the_program_is_registered():
@@ -101,6 +114,29 @@ def test_a_configuration_that_departs_from_the_program_is_registered():
     assert harness.workload_arch(config("yi-9b")) == "yi-9b"
     mamba = get_arch(harness.workload_arch(config("mamba2-780m")))
     assert mamba.vocab_size == 50288 and mamba.tie_embeddings
+
+
+def test_sizes_the_program_departs_from_are_named():
+    """One expert more, another layer list, or a size no reference counts
+    (grouped B/C projections): each is named."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.configs.base import LayerSpec
+
+    model = {"kind": "transformer", "num_hidden_layers": 28,
+             "n_routed_experts": 64, "first_k_dense_replace": 1,
+             "layers": [{"mixer": "attention", "ffn": "moe"}] * 28}
+    arch = get_arch("deepseek-moe-16b")
+    assert harness.size_mismatches(model, arch) == []
+    more = dataclasses.replace(arch, n_experts=65)
+    assert any("n_experts=65" in m
+               for m in harness.size_mismatches(model, more))
+    dense = dataclasses.replace(arch, pattern=(LayerSpec(),))
+    assert any("layer 1" in m for m in harness.size_mismatches(model, dense))
+    grouped = dict(model, n_groups=8)
+    assert any("n_groups" in m
+               for m in harness.size_mismatches(grouped, arch))
 
 
 FOUR_CHIP_RUN = """
