@@ -4,7 +4,7 @@ driven through the launchers' own ``main()`` entry points, all in this one
 process (a chip belongs to one process at a time).
 
     python3 chip_smoke.py               # one chip: phases A and B
-    python3 chip_smoke.py --four-chips  # four chips: phase C only
+    python3 chip_smoke.py --four-chips  # four chips: phases C and D
 
 Phase A  ``launch.explore``: BayesOpt with the device GP (``--gp jax``) over
          llama2-7b's generation workload at full width (6.7B bf16 params,
@@ -19,6 +19,13 @@ Phase C  ``launch.explore`` at ``--chips 4`` (tp=4 on the real mesh) beside
          cell on a (1, 4) mesh against a (1, 1) mesh with the same weights:
          the sharded bf16 logits must be as close to a float32 one-chip
          reference as the one-chip bf16 logits are.
+Phase D  Yi-9B on the four chips, tensor-parallel 4 ways: the 48-layer
+         build of the exploration loop (``make_build_fn``) must count the
+         per-device wire bytes and FLOPs of prefill and decode within 5% of
+         the plain count (``tests/plain_llama.py``); and at published
+         widths with 2 layers, the float32 prefill, then decode through the
+         cache, run on the chips must match the plain float32 forward on one
+         chip within 1e-4 of the largest |logit|.
 
 The last line of stdout is ``{"ok": true, "device": {...}}``.  Any failure,
 and a run that finds no TPU, exits non-zero without that line.
@@ -49,6 +56,12 @@ EXPLORE_4CHIP = ["--workload", "llama2-7b", "--shape", "generate",
                  "--chips", "4", "--clients", "1", "--samples", "4",
                  "--algorithm", "random", "--batch-size", "4", "--seed", "0"]
 TP_PREFILL = ("tinyllama-1.1b", 4, 256)          # arch, batch, prompt
+# phase D: the exploration loop's Yi-9B generation workload on four chips
+YI_EXPLORE = ["--workload", "yi-9b", "--shape", "generate", "--chips", "4",
+              "--clients", "1", "--prompt-len", "64", "--gen-tokens", "150"]
+COUNT_RTOL = 0.05           # per-device wire bytes and FLOPs vs plain count
+LOGIT_RTOL = 1e-4           # float32 tp=4 logits vs the plain forward
+YI_DECODE_STEPS = 4
 
 
 class SmokeFailure(Exception):
@@ -222,14 +235,93 @@ def phase_c():
           f"tp=4 bf16 logits stray {e4:.3e} from float32, one chip {e1:.3e}")
 
 
+def phase_d():
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from plain_llama import forward, tp_step_counts
+    from repro.configs import get_arch
+    from repro.core.jconfig import JConfig, TestConfig
+    from repro.launch import explore
+    from repro.launch.build import build_generation
+    from repro.launch.mesh import make_mesh_dp_tp
+    from repro.models import BuildFlags, Model
+
+    check(len(jax.devices()) >= 4, f"{len(jax.devices())} devices, want 4")
+    args = explore.parse_args(YI_EXPLORE)
+    arch = get_arch("yi-9b")
+    space = explore.generation_space(arch, args.chips)
+    knobs = {k.name: k.values[-1] for k in space}
+    pre, meta = explore.make_build_fn(args, JConfig(space, n_chips=4))(
+        TestConfig(0, arch.name, "generate", knobs))
+    sizes = dict(layers=arch.n_layers, d_model=arch.d_model,
+                 n_heads=arch.n_heads, n_kv_heads=arch.n_kv_heads,
+                 head_dim=arch.d_head, d_ff=arch.d_ff, vocab=arch.vocab_size,
+                 tp=4, act_bytes=2)
+    max_len = args.prompt_len + args.gen_tokens + 1
+    for art, tokens, context in (
+            (pre, args.prompt_len, args.prompt_len),
+            (meta["decode_artifact"], 1, max_len)):
+        kind = "prefill" if tokens > 1 else "decode"
+        wire, flops = tp_step_counts(tokens=tokens, context=context, **sizes)
+        log(f"yi-9b tp=4 {kind} per device: wire {art.wire_bytes_per_device:.6g}"
+            f" B (plain {wire:.6g}, {art.wire_bytes_per_device / wire:.4f}x), "
+            f"flops {art.flops_per_device:.6g} (plain {flops:.6g}, "
+            f"{art.flops_per_device / flops:.4f}x); collectives "
+            f"{art.collectives}; loop trips {art.loop_trips}")
+        for got, want, what in ((art.wire_bytes_per_device, wire, "wire"),
+                                (art.flops_per_device, flops, "flops")):
+            check(abs(got / want - 1) <= COUNT_RTOL,
+                  f"yi-9b tp=4 {kind} {what} {got:.6g} against {want:.6g}")
+
+    small = dataclasses.replace(arch, n_layers=2, name="yi-9b-2l")
+    prompt = args.prompt_len
+    max_len = prompt + YI_DECODE_STEPS + 1
+    params = Model(small, BuildFlags(dtype="float32")).init(jax.random.key(0))
+    tokens = np.asarray(jax.random.randint(
+        jax.random.key(1), (prompt + YI_DECODE_STEPS,), 0, small.vocab_size),
+        np.int32)
+    with jax.default_matmul_precision("highest"):
+        pre_c, dec_c = build_generation(small, make_mesh_dp_tp(1, 4),
+                                        BuildFlags(dtype="float32"), batch=1,
+                                        prompt_len=prompt, max_len=max_len)
+    p_sh, b_sh = pre_c.compiled.input_shardings[0]
+    logits, caches = pre_c.compiled(
+        jax.device_put(params, p_sh),
+        jax.device_put({"tokens": tokens[None, :prompt]}, b_sh))
+    rows = [np.asarray(logits)[0]]
+    d_sh = dec_c.compiled.input_shardings[0]
+    caches = jax.device_put(jax.tree.map(
+        lambda c: np.pad(np.asarray(c), [(0, 0)] * (c.ndim - 3)
+                         + [(0, max_len - prompt), (0, 0), (0, 0)]),
+        caches), d_sh[2])
+    params_d = jax.device_put(params, d_sh[0])
+    for j in range(YI_DECODE_STEPS):
+        logits, caches = dec_c.compiled(
+            params_d, jax.device_put(tokens[None, prompt + j:prompt + j + 1],
+                                     d_sh[1]),
+            caches, jax.device_put(np.int32(prompt + j), d_sh[3]))
+        rows.append(np.asarray(logits)[0])
+    one_chip = jax.devices()[0]
+    ref = forward(jax.device_put(params, one_chip), tokens)
+    want = ref[prompt - 1:prompt + YI_DECODE_STEPS]
+    gap = float(np.abs(np.stack(rows) - want).max() / np.abs(want).max())
+    log(f"yi-9b 2 layers at published widths, float32 tp=4 prefill + "
+        f"{YI_DECODE_STEPS} decode steps vs the plain float32 forward: max "
+        f"rel diff {gap:.3e} (tolerance {LOGIT_RTOL:g})")
+    check(gap <= LOGIT_RTOL, f"yi-9b tp=4 logits stray {gap:.3e}")
+
+
 def main():
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--four-chips", action="store_true",
-                   help="run only phase C, on four chips")
+                   help="run phases C and D, on four chips")
     args = p.parse_args()
     if not os.environ.get("JAX_PLATFORMS"):
         os.environ["JAX_PLATFORMS"] = "tpu"     # before JAX: no CPU fallback
-    sys.path.insert(0, os.path.join(HERE, "src"))
+    sys.path[:0] = [os.path.join(HERE, "src"), os.path.join(HERE, "tests")]
     from repro.launch.compile_cache import enable_compile_cache
 
     import jax
@@ -243,7 +335,7 @@ def main():
         return 2
     log(f"compile cache: {enable_compile_cache()}")
     os.makedirs(RESULTS, exist_ok=True)
-    phases = ([("C", phase_c)] if args.four_chips
+    phases = ([("C", phase_c), ("D", phase_d)] if args.four_chips
               else [("A", phase_a), ("B", phase_b)])
     try:
         for name, fn in phases:

@@ -111,6 +111,11 @@ def build_cell(arch: ArchConfig, shape: ShapeConfig, mesh,
 def build_generation(arch: ArchConfig, mesh, flags: BuildFlags = BuildFlags(),
                      batch: int = 1, prompt_len: int = 64, max_len: int = 256,
                      ) -> Tuple[BuiltCell, BuiltCell]:
+    """Prefill and decode cells, sharded over heads as ``launch.serve`` runs
+    the model (``sp`` off): a prompt's residual stream is too small for
+    sequence parallelism to pay, and sequence-sharded attention on a
+    tensor-parallel mesh gathers each layer's whole output projection."""
+    flags = dataclasses.replace(flags, sp=False)
     pre = ShapeConfig("gen_prefill", "prefill", prompt_len, batch)
     dec = ShapeConfig("gen_decode", "decode", max_len, batch)
     return (build_cell(arch, pre, mesh, flags),

@@ -223,9 +223,11 @@ def make_build_fn(args, jc):
                 arch, mesh, flags, batch=1,
                 prompt_len=args.prompt_len,
                 max_len=args.prompt_len + args.gen_tokens + 1)
-            with span("jx.build.analyze"):
+            with span("jx.build.analyze") as sp:
                 pre = summarize(pre_cell.compiled, mesh.size)
                 dec = summarize(dec_cell.compiled, mesh.size)
+                sp.set_metadata(**pre.count_stats("pre_"),
+                                **dec.count_stats("dec_"))
                 pre.hbm_est_per_device = analytic_hbm_bytes_per_device(
                     arch, ShapeConfig("p", "prefill", args.prompt_len, 1),
                     flags, mesh.size, dp, tp)
@@ -238,8 +240,9 @@ def make_build_fn(args, jc):
                          "n_decode_tokens": args.gen_tokens}
         shape = SHAPES[tc.shape]
         cell = build_cell(arch, shape, mesh, flags)
-        with span("jx.build.analyze"):
+        with span("jx.build.analyze") as sp:
             art = summarize(cell.compiled, mesh.size)
+            sp.set_metadata(**art.count_stats(""))
             art.hbm_est_per_device = analytic_hbm_bytes_per_device(
                 arch, shape, flags, mesh.size, dp, tp,
                 optimizer=cell.meta.get("optimizer", "adamw"))

@@ -204,8 +204,12 @@ class ShardingPolicy:
             seq_axes = (self.tp_axis,) if bspec is not None else (
                 tuple(self.dp_axes) + (self.tp_axis,))
             seq_axes = tuple(a for a in seq_axes if a is not None) or None
-            return P(*([None] * lead), bspec,
-                     self._fits(s, seq_axes), None, None)
+            sspec = self._fits(s, seq_axes)
+            # a cache length the model axis does not divide (a prompt plus
+            # its generated tokens) shards the KV heads instead, as the
+            # decode step's K/V projections are sharded
+            hspec = None if sspec is not None else self._fits(hkv, self.tp_axis)
+            return P(*([None] * lead), bspec, sspec, hspec, None)
         if path.endswith("state"):                    # (B, H, P, N)
             b, h = shape[lead], shape[lead + 1]
             return P(*([None] * lead), self._fits(b, self.dp),

@@ -32,11 +32,42 @@ def test_collective_parsing_crafted():
     got = collective_wire_bytes(CRAFTED_HLO, 4)
     assert got["all-gather"] == pytest.approx(
         4 * 1024 * 1024 * 3 / 4       # main all-gather
-        + (64 * 4 * 2) * 3 / 4)       # -start tuple counted once
+        + (64 * 4) * 3 / 4)           # -start: its tuple's result, once
     assert got["all-reduce"] == pytest.approx(2 * 512 * 2 * 1 / 2)  # group of 2
     assert got["reduce-scatter"] == pytest.approx(256 * 4 * 3)
     assert got["collective-permute"] == pytest.approx(128 * 4)
     assert "add" not in got
+
+
+@pytest.mark.parametrize("lhs,rhs,kw", [
+    ((2, 3, 16, 16), (8, 3, 3, 3), dict(window_strides=(1, 1),
+                                        padding="SAME")),
+    ((2, 4, 15, 9), (6, 2, 3, 2), dict(window_strides=(2, 1),
+                                       padding=((1, 2), (0, 1)),
+                                       lhs_dilation=(2, 1),
+                                       rhs_dilation=(1, 2),
+                                       feature_group_count=2)),
+    ((1, 8, 32), (8, 8, 5), dict(window_strides=(3,), padding="VALID",
+                                 rhs_dilation=(2,))),
+    ((64, 48), (48, 32), None),
+], ids=["same", "dilated-grouped", "strided-1d", "dot"])
+def test_matmul_flops_equal_cost_analysis_outside_loops(lhs, rhs, kw):
+    """Outside any loop the HLO matmul count is XLA's own: padding,
+    dilation holes, strides and feature groups as cost analysis counts
+    them."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.roofline.analysis import HloCounts
+
+    def f(a, b):
+        if kw is None:
+            return a @ b
+        return jax.lax.conv_general_dilated(a, b, **kw)
+
+    compiled = jax.jit(f).lower(jnp.zeros(lhs), jnp.zeros(rhs)).compile()
+    assert HloCounts(compiled.as_text(), 1).flops() == pytest.approx(
+        compiled.cost_analysis()["flops"])
 
 
 def test_hw_model_terms_and_ladders():

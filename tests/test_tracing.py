@@ -126,6 +126,19 @@ def test_spans_carry_their_stats(sweep_spans):
     assert kinds == {"prefill", "decode"}
 
 
+def test_the_analysis_span_counts_the_layer_loop_per_trip(sweep_spans):
+    """Each build's analysis records the trips it applied: the reduced
+    Mamba-2's two scanned layers, and in prefill the SSD's scan over the
+    16-token prompt's two chunks of 8; no loop counted once for want of a
+    trip count, and no wire bytes on one chip."""
+    stats = [s.stats for s in sweep_spans if s.name == "jx.build.analyze"]
+    assert stats
+    for st in stats:
+        assert (st["pre_trips"], st["dec_trips"]) == (2 + 2, 2)
+        assert st["pre_unknown_trip_loops"] == st["dec_unknown_trip_loops"] == 0
+        assert not [k for k in st if "_wire_" in k]
+
+
 def test_a_batch_shares_its_config_id_between_host_and_board(sweep_spans):
     def cids(name):
         return sorted(s.stats["cid"] for s in sweep_spans if s.name == name)
