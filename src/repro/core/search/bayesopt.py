@@ -25,7 +25,9 @@ pre-incremental path, retained for benchmarking and equivalence tests).
 (``repro.core.search.gp_jax.JaxIncrementalGP``): jitted donated-buffer
 rank-appends, fused pool scoring in one device call, and a subset-of-data
 inducing-point approximation past ``inducing_threshold`` points so ask
-latency stays flat at 10⁴+ observations.  ``gp_mode="pallas"`` keeps that
+latency stays flat at 10⁴+ observations; a ParEGO ask stages its picks'
+scalarisations (``stage``) so their fits and posteriors run as one program
+each.  ``gp_mode="pallas"`` keeps that
 layout but swaps the two hot device calls for tiled Pallas kernels
 (``repro.core.search.gp_pallas.PallasIncrementalGP``): the rank-append
 Cholesky runs its triangular work over active-row tiles only (O(n·block)
@@ -713,9 +715,14 @@ class BayesOpt(SearchAlgorithm):
                 self._take_best(idx, flats, np.argsort(-score), n, out)
             return out
 
-        for _ in range(n):   # parego: fresh scalarisation per pick
+        # parego: a fresh scalarisation per pick, all drawn first so the
+        # device GP stages their fits and posteriors as one program each
+        with span("jx.search.acquire"):
+            ss = [self._scalarise(ys) for _ in range(n)]
+            if self.gp_mode in DEVICE_GP_MODES:
+                gp.stage(np.stack(ss, axis=1), xp)
+        for s in ss:
             with span("jx.search.acquire"):
-                s = self._scalarise(ys)
                 mu, sig = gp.fit_y(s).predict(xp)
                 score = expected_improvement(mu, sig, float(np.min(s)))
                 self._take_best(idx, flats, np.argsort(-score), len(out) + 1,

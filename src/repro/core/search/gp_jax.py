@@ -25,6 +25,13 @@ buffers and runs every hot step as one jitted call:
   hyperparameters runs only the mean, so ParEGO's picks over one pool pay
   for one pool copy and one variance product per ask.  Every reassignment
   of the factor bumps ``_version``, which drops the reuse.
+* **Staged picks** — ``stage`` fits a block of targets, one column each,
+  and scores them all over a pool in one fit and one predict program.  A
+  later ``fit_y`` on targets equal to a staged column, and the ``predict``
+  on equal rows that follows it, take that column's posterior with no
+  device call; the block comes to the host in one fetch.  So ParEGO's
+  picks of an ask, each with its own scalarisation, cost one target copy,
+  two dispatches and one round trip between them.
 * **Inducing points (subset-of-data)** — every observation lands in a
   host-side archive, but past ``inducing_threshold`` active points the
   factor is periodically *thinned* back to an evenly-strided subset of the
@@ -239,6 +246,18 @@ def _ehvi_jit(xb, alpha, n, xq, front, ref, ym, ysd, ls, signal):
     return jnp.sum(width * height, axis=1)
 
 
+class _Staged:
+    """A ``stage()``: its key and pool rows, the raw target columns with
+    their means and stds, the device alpha (cap, J) and posterior (P, J+1),
+    and that posterior's host copy once fetched."""
+
+    def __init__(self, key, rows, cols, ym, ys, alpha, post):
+        self.key, self.rows, self.cols = key, rows, cols
+        self.ym, self.ys = ym, ys
+        self.alpha, self.post = alpha, post
+        self.out: Optional[np.ndarray] = None
+
+
 class JaxIncrementalGP:
     """Drop-in for ``IncrementalGP`` with device buffers + inducing points.
 
@@ -271,13 +290,19 @@ class JaxIncrementalGP:
         self.n_rethins = 0      # thins that reused the buffers in place
         self.n_predicts = 0
         self.n_predict_reuses = 0   # predicts that reused the pool's sd
+        self.n_staged = 0           # predicts served from a stage()
         # bumped whenever the factor (_xb, _lb, _lib, _n) is reassigned;
         # _pool holds (_pool_key(), rows, device pool, sd) of the last full
-        # predict
+        # predict, its sd None while that is a stage() not fetched yet
         self._version = 0
         self._pool = None
-        # fit state (single- and multi-target kept separate, like numpy)
+        self._staged: Optional[_Staged] = None
+        # fit state (single- and multi-target kept separate, like numpy);
+        # the single-target mean is column _col1 of _alpha1, and _served the
+        # stage a served fit_y took it from
         self._alpha1 = self._ym = self._ys = None
+        self._col1 = 0
+        self._served: Optional[_Staged] = None
         self._alpha_m = self._ym_m = self._ys_m = None
 
     def __len__(self) -> int:
@@ -437,10 +462,19 @@ class JaxIncrementalGP:
 
     def fit_y(self, y: np.ndarray) -> "JaxIncrementalGP":
         assert self._n > 0, "observe first"
-        ya = self._active_targets(np.asarray(y, float))
+        y = np.asarray(y, float)
+        j = self._staged_column(y)
+        if j is not None:
+            st = self._served = self._staged
+            with span("jx.gp.fit_y", cap=self._cap, rows=self._n, staged=1):
+                self._alpha1, self._col1 = st.alpha, j
+                self._ym, self._ys = st.ym[j], st.ys[j]
+            return self
+        ya = self._active_targets(y)
         self._ym = float(np.mean(ya))
         self._ys = float(np.std(ya)) or 1.0
-        with span("jx.gp.fit_y", cap=self._cap, rows=self._n), \
+        self._col1, self._served = 0, None
+        with span("jx.gp.fit_y", cap=self._cap, rows=self._n, staged=0), \
                 jax.enable_x64(True):
             self._alpha1 = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym) / self._ys)[:, None])
@@ -460,6 +494,59 @@ class JaxIncrementalGP:
             self._alpha_m = _fit_y_jit(
                 self._lib, self._padded((ya - self._ym_m) / self._ys_m))
         return self
+
+    # -- staged picks ----------------------------------------------------------
+    def stage(self, targets: np.ndarray, xs: np.ndarray) -> "JaxIncrementalGP":
+        """Fit each column of ``targets`` and score every column over the
+        pool ``xs``, ahead of the per-pick ``fit_y`` and ``predict`` calls.
+
+        Each column is standardised as ``fit_y`` does it (its own mean, its
+        std or 1.0), the columns are padded to a power of two and copied in
+        one transfer, and one ``_fit_y_jit`` and one ``_predict_jit`` run
+        over them all.  Nothing is fetched here: the first served predict
+        copies the (M, J+1) posterior to the host.  The stage holds while
+        ``_pool_key()`` does, and becomes the pool a later unstaged predict
+        on the same rows reuses the sd of."""
+        assert self._n > 0, "observe first"
+        xs = np.atleast_2d(np.asarray(xs, float))
+        cols = [np.ascontiguousarray(c)
+                for c in np.asarray(targets, float).reshape(
+                    len(targets), -1).T]
+        yn = np.zeros((self._cap, _pow2_small(len(cols))))
+        ym, ys = [], []
+        for j, c in enumerate(cols):
+            ya = self._active_targets(c)
+            ym.append(float(np.mean(ya)))
+            ys.append(float(np.std(ya)) or 1.0)
+            yn[:self._n, j] = (ya - ym[j]) / ys[j]
+        key = self._pool_key()
+        with span("jx.gp.stage", cap=self._cap, rows=len(xs),
+                  targets=len(cols)):
+            xq, _ = self._pad_pool(xs)
+            with jax.enable_x64(True):
+                alpha = _fit_y_jit(self._lib, jnp.asarray(yn))
+                post = _predict_jit(self._xb, self._lib, alpha,
+                                    np.int32(self._n), xq, self.ls,
+                                    self.signal)
+        self._staged = _Staged(key, xs.copy(), cols, ym, ys, alpha, post)
+        self._pool = (key, self._staged.rows, xq, None)
+        return self
+
+    def _staged_column(self, y: np.ndarray) -> Optional[int]:
+        """The staged column equal to the targets ``y``, if the stage
+        still holds."""
+        st = self._staged
+        if st is None or st.key != self._pool_key():
+            return None
+        return next((j for j, c in enumerate(st.cols)
+                     if np.array_equal(c, y)), None)
+
+    @staticmethod
+    def _staged_out(st: _Staged) -> np.ndarray:
+        """The stage's (M, J+1) posterior on the host, fetched once."""
+        if st.out is None:
+            st.out = _fetch(st.post)[:len(st.rows)]
+        return st.out
 
     # -- predicts -------------------------------------------------------------
     def _pad_pool(self, xs: np.ndarray):
@@ -498,6 +585,8 @@ class JaxIncrementalGP:
                   reuse=int(reuse)):
             if reuse:
                 xq, sd = pool[2], pool[3]
+                if sd is None:
+                    sd = np.sqrt(self._staged_out(self._staged)[:, -1])
                 with jax.enable_x64(True):
                     mu = _predict_mean_jit(self._xb, alpha, np.int32(self._n),
                                            xq, self.ls, self.signal)
@@ -512,8 +601,23 @@ class JaxIncrementalGP:
             return out[:, :-1], sd
 
     def predict(self, xs: np.ndarray):
-        mu, sd = self._posterior(xs, self._alpha1)
-        return mu[:, 0] * self._ys + self._ym, sd * self._ys
+        st = self._served
+        if (st is not None and st.key == self._pool_key()
+                and np.array_equal(st.rows, np.atleast_2d(xs))):
+            # the served fit_y's column of the staged posterior; the first
+            # such predict of a stage fetches it, the rest reuse it
+            reuse = st.out is not None
+            self.n_predicts += 1
+            self.n_predict_reuses += reuse
+            self.n_staged += 1
+            with span("jx.gp.predict", cap=self._cap, rows=len(st.rows),
+                      reuse=int(reuse), staged=1):
+                out = self._staged_out(st)
+            mu, sd = out[:, self._col1], np.sqrt(out[:, -1])
+        else:
+            mu, sd = self._posterior(xs, self._alpha1)
+            mu = mu[:, self._col1]
+        return mu * self._ys + self._ym, sd * self._ys
 
     def predict_multi(self, xs: np.ndarray):
         mu, sd = self._posterior(xs, self._alpha_m)
@@ -563,7 +667,8 @@ class JaxIncrementalGP:
                 "capacity": self._cap, "appends": self.n_appends,
                 "refactors": self.n_refactors, "thins": self.n_thins,
                 "rethins": self.n_rethins, "predicts": self.n_predicts,
-                "predict_reuses": self.n_predict_reuses}
+                "predict_reuses": self.n_predict_reuses,
+                "staged": self.n_staged}
 
     # -- durable state ---------------------------------------------------------
     def state_dict(self) -> dict:
@@ -623,6 +728,8 @@ class JaxIncrementalGP:
         self.n_thins = state["n_thins"]
         self.n_rethins = state["n_rethins"]
         self._version += 1
+        self._staged = self._served = None
         self._alpha1 = self._ym = self._ys = None
+        self._col1 = 0
         self._alpha_m = self._ym_m = self._ys_m = None
         return self

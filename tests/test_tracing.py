@@ -126,6 +126,24 @@ def test_spans_carry_their_stats(sweep_spans):
     assert kinds == {"prefill", "decode"}
 
 
+def test_the_parego_ask_stages_its_picks_inside_acquire(sweep_spans):
+    """Each ParEGO ask stages its four picks over the pool, inside a
+    ``jx.search.acquire`` span, and every pick's fit and predict is served
+    from the stage."""
+    stages = [s for s in sweep_spans if s.name == "jx.gp.stage"]
+    assert stages
+    for s in stages:
+        assert inside(s, {"jx.search.acquire"}, sweep_spans), s
+        assert s.stats["targets"] == 4 and s.stats["cap"] >= 16
+    pools = {s.stats["rows"] for s in sweep_spans
+             if s.name == "jx.search.pool"}
+    assert {s.stats["rows"] for s in stages} <= pools
+    picks = [s for s in sweep_spans if s.name in ("jx.gp.fit_y",
+                                                  "jx.gp.predict")]
+    assert len(picks) == 8 * len(stages)
+    assert all(s.stats["staged"] == 1 for s in picks)
+
+
 def test_the_analysis_span_counts_the_layer_loop_per_trip(sweep_spans):
     """Each build's analysis records the trips it applied: the reduced
     Mamba-2's two scanned layers, and in prefill the SSD's scan over the
