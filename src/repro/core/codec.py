@@ -11,14 +11,12 @@ decides how a framed dict becomes bytes on the wire:
   ``batchc`` frame: config_id lists, hw-ladder knob columns, metric columns)
   out of the JSON body and packs it as a little-endian typed array
   (int64 / float64 / uint8-bool).  A *constant string column* — every
-  element the same string, e.g. the per-chunk ``tenant`` tag a
-  multi-tenant ``ExploreService`` stamps on each config (chunks are
-  single-tenant, so the column is always uniform) — collapses to one
-  ``(value, count)`` entry instead of N JSON copies.  Other strings and
-  mixed columns stay in the JSON skeleton, so the codec is lossless and
-  type-exact: ints stay ints, floats round-trip bit-for-bit (no decimal
-  text detour), bools stay bools.  A message with nothing to pack
-  degenerates to plain JSON bytes.
+  element the same string, e.g. the ``arch`` and ``shape`` of a chunk's
+  configs — collapses to one ``(value, count)`` entry instead of N JSON
+  copies.  Other strings and mixed columns stay in the JSON skeleton, so
+  the codec is lossless and type-exact: ints stay ints, floats round-trip
+  bit-for-bit (no decimal text detour), bools stay bools.  A message with
+  nothing to pack degenerates to plain JSON bytes.
 
 Bytes payloads (artifact blobs)
 -------------------------------
@@ -155,7 +153,7 @@ class BinaryCodec(Codec):
                     continue
                 if len(v) > 1 and type(v[0]) is str \
                         and all(type(x) is str and x == v[0] for x in v):
-                    # constant string column (e.g. a chunk's tenant tags):
+                    # constant string column (e.g. a chunk's arch or shape):
                     # one (value, count) entry, no blob segment
                     packed.append({"k": list(path) + [k], "t": "c",
                                    "n": len(v), "v": v[0]})

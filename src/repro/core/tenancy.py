@@ -1,12 +1,25 @@
-"""Multi-tenant exploration service — many sweeps, one fleet.
+"""The exploration loop — one sweep or many, one fleet (paper §III,
+Algorithm 1, the JHOST procedure).
 
-``JHost.explore`` runs one sweep then exits, so a fleet serving several
-users spends most of its wall clock idle between back-to-back runs.
-``ExploreService`` is the long-lived daemon that turns that idle time into
-throughput: it owns ONE shared ``DispatchScheduler`` + transport + optional
-``FleetArtifactStore`` and multiplexes K tenants over them, each tenant
+``ExploreService`` owns ONE ``DispatchScheduler`` + transport + optional
+``FleetArtifactStore`` and multiplexes K tenant sweeps over them, each
 with its own search algorithm (or async ``SearchDriver``, any gp_mode),
-``ResultStore``, objectives, and optional checkpoint directory.
+``ResultStore``, objectives, and optional checkpoint directory.  A solo
+sweep is a one-tenant run of the same loop: ``JHost.explore`` submits it
+under the scheduler's implicit ``"default"`` tenant and runs the service
+until it completes, so there is one host loop to trace, tune and keep
+durable.
+
+Each turn of ``run()`` asks every active tenant's searcher for its share
+of the fleet's open capacity (write-ahead logging the ask when durable),
+pushes the scheduler's ready chunks, pulls the result stream, splits off
+membership (HELLO/GOODBYE), control and ``artifact_*`` frames, tells the
+results back, records terminal timeouts of stragglers, snapshots and
+detects a stuck fleet.  Dispatch policy, adaptive chunk sizing, deadlines,
+speculation and compile-affinity placement live in the scheduler
+(``repro.core.scheduler``); the loop only moves data between it, the
+searchers, the transport and the stores.  Its ``jx.host.*`` spans
+(``repro.core.tracing``) run on the loop's own thread.
 
 How the multiplexing works
 --------------------------
@@ -23,21 +36,17 @@ How the multiplexing works
   like a solo run's: the service issues a fleet-global id per config and
   maps it back to the tenant-local id (0..n-1 in ask order) before a
   record is stored, told to the search, logged, or streamed.  Per-tenant
-  metrics are therefore bit-identical to the same sweep run alone.
-* **Wire tagging** — outbound chunks carry a ``"tenant"`` sidecar key on
-  each config dict and results may echo it back.  Old clients tolerate
-  the extra key (``TestConfig.from_wire`` reads only its four fields) and
-  old hosts ignore the echo (``ResultRecord.from_wire`` filters unknown
-  keys), so mixed fleets keep working.
+  metrics are therefore bit-identical to the same sweep run alone, and a
+  solo sweep puts its local ids on the wire, resumed or not.
 * **Streaming** — ``subscribe(tenant)`` yields each ``ResultRecord`` as it
   lands, ending when the sweep completes or is cancelled; the
   ``CONTROL_SUBMIT/STATUS/CANCEL`` verbs (``repro.core.transport``) expose
   the same operations over the wire / stdin for ``launch.serve_explore``.
 * **Durability** — a tenant submitted with ``checkpoint_dir`` (or under a
   service-wide ``checkpoint_root``, namespaced per tenant by
-  ``durable.tenant_checkpoint_dir``) gets the full PR 9 snapshot + WAL
-  treatment, logged in tenant-local ids so ``resume`` works whether the
-  sweep is later re-run solo or under the service.
+  ``durable.tenant_checkpoint_dir``) gets the snapshot + WAL treatment of
+  ``repro.core.durable``, logged in tenant-local ids so ``resume`` works
+  whether the sweep is later re-run solo or under the service.
 """
 from __future__ import annotations
 
@@ -51,15 +60,32 @@ from repro.core.durable import (ExplorationCheckpoint, restore_sweep,
                                 tenant_checkpoint_dir)
 from repro.core.fleet import FleetArtifactStore
 from repro.core.jconfig import TestConfig
-from repro.core.jhost import handle_membership
 from repro.core.results import ResultRecord, ResultStore
 from repro.core.scheduler import DispatchScheduler
-from repro.core.transport import (CONTROL_CANCEL, CONTROL_STATUS,
-                                  CONTROL_SUBMIT, HostTransport,
-                                  is_artifact_msg, is_control_msg,
-                                  is_membership_msg)
+from repro.core.tracing import span
+from repro.core.transport import (CLIENT_HELLO, CONTROL_CANCEL,
+                                  CONTROL_STATUS, CONTROL_SUBMIT,
+                                  HostTransport, is_artifact_msg,
+                                  is_control_msg, is_membership_msg)
 
 _DONE = object()          # subscriber sentinel: the sweep is finished
+# the scheduler's implicit tenant: a solo sweep rides it, so the scheduler
+# keeps its single-tenant shape (live ``pending`` deque, unsplit stats)
+SOLO_TENANT = "default"
+
+
+def stop_clients(transport: HostTransport) -> None:
+    """Send every client ``stop``; report a board that cannot take it."""
+    failed = []
+    for c in transport.client_ids():
+        try:
+            transport.push(c, {"cmd": "stop"})
+        except Exception as e:
+            failed.append((c, e))
+    for c, e in failed:
+        # a push that cannot even be queued means the board is hung or
+        # its path is gone — surface it instead of silently leaking it
+        print(f"# jhost: client {c} failed to take stop: {e!r}")
 
 
 class TenantSweep:
@@ -67,7 +93,7 @@ class TenantSweep:
 
     __slots__ = ("name", "search", "arch", "shape", "n_samples", "objectives",
                  "store", "ckpt", "checkpoint_every", "fingerprint_fn",
-                 "issued", "completed", "last_snap", "local_next",
+                 "completed", "last_snap", "local_next",
                  "outstanding", "subscribers", "state",
                  "poll_ask", "note_demand", "note_residency")
 
@@ -87,24 +113,35 @@ class TenantSweep:
         self.ckpt = ckpt
         self.checkpoint_every = checkpoint_every
         self.fingerprint_fn = fingerprint_fn
-        self.issued = 0
         self.completed = 0
         self.last_snap = 0
         self.local_next = 0              # tenant-local config-id counter
         self.outstanding: Dict[int, dict] = {}   # local id -> knobs
         self.subscribers: List[queue.Queue] = []
         self.state = "running"           # running | done | cancelled
-        # async SearchDriver hooks (None for plain algorithms)
+        # an async SearchDriver exposes poll_ask/note_demand: the loop tops
+        # the pipeline up from its precomputed buffer without blocking on
+        # search math while results are in flight (None for plain ones)
         self.poll_ask = getattr(search, "poll_ask", None)
         self.note_demand = getattr(search, "note_demand", None)
-        self.note_residency = getattr(search, "note_residency", None)
+        # shadow-aware pools: with a fingerprint_fn the searcher learns which
+        # sw fingerprints are resident in the fleet's cache shadows and
+        # biases its candidate pools toward them (no-ops for searchers
+        # without the hooks)
+        self.note_residency = None
+        if fingerprint_fn is not None:
+            set_fp_fn = getattr(search, "set_sw_fingerprint_fn", None)
+            if set_fp_fn is not None:
+                set_fp_fn(lambda knobs: fingerprint_fn(
+                    TestConfig(-1, arch, shape, knobs)))
+            self.note_residency = getattr(search, "note_residency", None)
 
     def active(self) -> bool:
         return self.state == "running"
 
 
 class ExploreService:
-    """Long-lived multi-tenant exploration daemon over one shared fleet.
+    """The exploration loop over one shared fleet, for one or many sweeps.
 
     Construct it with a host transport (and the usual scheduler knobs),
     ``submit_sweep()`` any number of tenants — before or during ``run()``
@@ -136,26 +173,24 @@ class ExploreService:
         self.checkpoint_root = checkpoint_root
         self.checkpoint_every = checkpoint_every
         self.progress = progress
-        fleet_resident_fn = None
-        if fleet_store is not None:
-            fleet_resident_fn = \
-                lambda fp, _fs=fleet_store: _fs.resident_fp(repr(fp))
         # one scheduler for every tenant: the service-level fingerprint fn
-        # routes to the owning tenant's (spaces differ, namespace is shared)
+        # routes to the owning tenant's (spaces differ, namespace is
+        # shared); without affinity the first tenant that brings a
+        # fingerprint_fn installs it (submit_sweep)
         self.scheduler = DispatchScheduler(
             transport.client_ids(), policy=dispatch, timeout_s=timeout_s,
             max_retries=max_retries, batch_size=batch_size,
             chunk_budget_s=(None if chunk_budget_ms is None
                             else chunk_budget_ms / 1e3),
-            affinity=affinity, fingerprint_fn=self._tc_fingerprint,
+            affinity=affinity,
+            fingerprint_fn=(None if affinity == "off"
+                            else self._tc_fingerprint),
             client_cache_size=client_cache_size,
             speculate_frac=speculate_frac,
             speculate_slow_mult=speculate_slow_mult,
-            pipeline_depth=pipeline_depth,
-            fleet_resident_fn=fleet_resident_fn)
+            pipeline_depth=pipeline_depth)
         self.scheduler.wire_stats_fn = getattr(transport, "wire_summary",
                                                None)
-        self.quarantined = self.scheduler.quarantined   # shared live set
         if fleet_store is not None:
             # per-tenant served/assigned attribution: a client asking the
             # store for an artifact is evaluating its head chunk — that
@@ -184,7 +219,6 @@ class ExploreService:
                      fingerprint_fn: Optional[Callable] = None,
                      checkpoint_dir: Optional[str] = None,
                      checkpoint_every: Optional[int] = None,
-                     checkpoint_keep: int = 3,
                      resume: bool = False,
                      knob_names: Optional[Sequence[str]] = None) -> TenantSweep:
         """Register a tenant sweep; safe before or during ``run()``."""
@@ -192,6 +226,9 @@ class ExploreService:
             if name in self._sweeps and self._sweeps[name].active():
                 raise ValueError(f"tenant {name!r} already has a running "
                                  f"sweep (cancel it first)")
+            if fingerprint_fn is None and self.scheduler.affinity != "off":
+                raise ValueError("affinity placement needs a fingerprint_fn "
+                                 "(e.g. JConfig.cache_key)")
             if store is None:
                 store = ResultStore(csv_path=csv_path,
                                     knob_names=knob_names or (),
@@ -201,32 +238,55 @@ class ExploreService:
                                                        name)
             ckpt = None
             if checkpoint_dir is not None:
-                ckpt = ExplorationCheckpoint(checkpoint_dir,
-                                             keep=checkpoint_keep)
+                ckpt = ExplorationCheckpoint(checkpoint_dir)
+            if checkpoint_every is None:
+                checkpoint_every = self.checkpoint_every
             sweep = TenantSweep(name, search, arch, shape, n_samples,
-                                objectives, store, ckpt,
-                                checkpoint_every or self.checkpoint_every,
+                                objectives, store, ckpt, checkpoint_every,
                                 fingerprint_fn)
+            if fingerprint_fn is not None:
+                # route the scheduler's fingerprints through the tenant's,
+                # and let placement consult the fleet store: a fingerprint
+                # it can serve is a fetch, not a compile, wherever it lands
+                self.scheduler.fingerprint_fn = self._tc_fingerprint
+                if self.fleet_store is not None:
+                    self.scheduler.fleet_resident_fn = \
+                        lambda fp, _fs=self.fleet_store: \
+                        _fs.resident_fp(repr(fp))
             self.scheduler.add_tenant(name, weight=weight, priority=priority,
                                       max_inflight=max_inflight)
             if ckpt is not None:
                 if resume:
-                    n_prior = store.resume_from_csv()
-                    info = restore_sweep(ckpt, search, store, objectives)
-                    sweep.outstanding = info["outstanding"]
-                    sweep.local_next = info["next_id"]
-                    sweep.completed = len(store.records)
-                    sweep.issued = sweep.completed + len(sweep.outstanding)
-                    sweep.last_snap = sweep.completed
-                    print(f"# tenancy[{name}]: resume re-adopted {n_prior} "
-                          f"rows, replayed {info['n_events']} events, "
-                          f"re-submitting {len(sweep.outstanding)} in-flight")
-                    for lid in sorted(sweep.outstanding):
-                        self._submit_one(sweep, lid, sweep.outstanding[lid])
+                    self._resume(sweep)
                 ckpt.open_log()
             self._sweeps[name] = sweep
             self._wake.set()
             return sweep
+
+    def _resume(self, sweep: TenantSweep) -> None:
+        n_prior = sweep.store.resume_from_csv()
+        info = restore_sweep(sweep.ckpt, sweep.search, sweep.store,
+                             sweep.objectives)
+        sweep.outstanding = info["outstanding"]
+        sweep.local_next = info["next_id"]
+        sweep.completed = sweep.last_snap = len(sweep.store.records)
+        mism = (f", {info['n_ask_mismatch']} ask replays diverged "
+                f"(logged knobs kept)" if info["n_ask_mismatch"] else "")
+        who = "" if self._solo() else f" [{sweep.name}]"
+        print(f"# resume: {n_prior} completed rows re-adopted, "
+              f"{info['n_events']} events replayed, "
+              f"{info['n_late_tells']} late tells, "
+              f"{len(sweep.outstanding)} in-flight configs "
+              f"re-submitted{mism}{who}")
+        # a board that outlived the crash may still answer under the wire
+        # id a config had then, so no wire id may take a second meaning.
+        # A solo sweep's wire ids are its local ids: it re-submits under
+        # them, and its fresh ids start past every id it issued
+        keep_ids = self._solo() and self._gid_next == 0
+        self._gid_next = max(self._gid_next, sweep.local_next)
+        for lid in sorted(sweep.outstanding):   # original ids, not re-asked
+            self._submit_one(sweep, lid, sweep.outstanding[lid],
+                             gid=lid if keep_ids else None)
 
     def cancel(self, name: str) -> Dict[str, int]:
         """Drop a tenant's queued work, orphan its in-flight configs, and
@@ -258,15 +318,14 @@ class ExploreService:
             return {n: self._status_one(s) for n, s in self._sweeps.items()}
 
     def _status_one(self, sweep: TenantSweep) -> Dict[str, Any]:
-        t = self.scheduler.tenants.get(sweep.name)
-        out = {"state": sweep.state, "issued": sweep.issued,
-               "completed": sweep.completed, "n_samples": sweep.n_samples,
-               "outstanding": len(sweep.outstanding)}
-        if t is not None:
-            out.update(queued=len(t.queue), inflight=t.inflight,
-                       weight=t.weight, priority=t.priority,
-                       deficit=round(t.deficit, 6), cost_ewma=t.cost_ewma)
-        return out
+        t = self.scheduler.tenants[sweep.name]   # registered for good
+        return {"state": sweep.state,
+                "issued": sweep.completed + len(sweep.outstanding),
+                "completed": sweep.completed, "n_samples": sweep.n_samples,
+                "outstanding": len(sweep.outstanding),
+                "queued": len(t.queue), "inflight": t.inflight,
+                "weight": t.weight, "priority": t.priority,
+                "deficit": round(t.deficit, 6), "cost_ewma": t.cost_ewma}
 
     def subscribe(self, name: str,
                   timeout_s: Optional[float] = None
@@ -296,7 +355,7 @@ class ExploreService:
         self._stop.set()
         self._wake.set()
 
-    # -- the shared dispatch loop ----------------------------------------------
+    # -- the exploration loop --------------------------------------------------
     def run(self, stop_when_idle: bool = True,
             idle_poll_s: float = 0.25) -> None:
         """Drive every registered sweep over the shared fleet.
@@ -304,43 +363,50 @@ class ExploreService:
         ``stop_when_idle=True`` (batch use): returns once all registered
         sweeps are done.  ``stop_when_idle=False`` (daemon use): keeps
         waiting for new ``submit_sweep``/control traffic until ``stop()``.
+        A loop that dies closes its sweeps' checkpoints, so each stays
+        resumable.
         """
         sched = self.scheduler
-        while not self._stop.is_set():
+        try:
+            while not self._stop.is_set():
+                with self._lock:
+                    active = [s for s in self._sweeps.values() if s.active()]
+                    if active:
+                        self._intake(active)
+                        for client, tcs in sched.next_dispatches():
+                            with span("jx.host.dispatch", n=len(tcs),
+                                      cid=tcs[0].config_id):
+                                self.transport.push_many(
+                                    client, [tc.to_wire() for tc in tcs])
+                with span("jx.host.pull") as sp:
+                    msgs = self.transport.pull_many(self.poll_s if active
+                                                    else idle_poll_s)
+                    sp.set_metadata(n_msgs=len(msgs))
+                with self._lock:
+                    self._drain(msgs)
+                    self._checkpoint_and_finish()
+                    active = [s for s in self._sweeps.values() if s.active()]
+                    if active and sched.stuck():
+                        self._stuck(active)
+                if not active:
+                    if stop_when_idle:
+                        return
+                    self._wake.wait(idle_poll_s)
+                    self._wake.clear()
+        except BaseException:
             with self._lock:
-                active = [s for s in self._sweeps.values() if s.active()]
-                if active:
-                    self._intake(active)
-                    for client, tcs in sched.next_dispatches():
-                        tenant = sched.tenant_of(tcs[0].config_id)
-                        self.transport.push_many(
-                            client, [dict(tc.to_wire(), tenant=tenant)
-                                     for tc in tcs])
-            msgs = self.transport.pull_many(self.poll_s if active
-                                            else idle_poll_s)
-            with self._lock:
-                self._drain(msgs)
-                self._checkpoint_and_finish()
-                active = [s for s in self._sweeps.values() if s.active()]
-                if active and sched.stuck():
-                    for s in active:
-                        s.store.close()      # keep every sweep resumable
-                    raise RuntimeError(
-                        f"all clients quarantined; service stuck with "
-                        f"tenants {[s.name for s in active]} unfinished "
-                        f"(scheduler stats: {sched.stats()})")
-            if not active:
-                if stop_when_idle:
-                    return
-                self._wake.wait(idle_poll_s)
-                self._wake.clear()
+                for s in self._sweeps.values():
+                    if s.ckpt is not None:
+                        s.ckpt.close()
+            raise
 
     def _intake(self, active: List[TenantSweep]) -> None:
         """Top every active tenant's queue up to its fair share of the
         fleet's open capacity with fresh asks from its own search."""
         sched = self.scheduler
         for sweep in active:
-            remaining = sweep.n_samples - sweep.issued
+            remaining = (sweep.n_samples - sweep.completed
+                         - len(sweep.outstanding))
             if remaining <= 0:
                 continue
             want = min(remaining, sched.want_for(sweep.name))
@@ -348,15 +414,15 @@ class ExploreService:
                 continue
             if sweep.note_residency is not None:
                 sweep.note_residency(sched.resident_fingerprints())
-            if sweep.poll_ask is not None:
-                if sweep.note_demand is not None:
-                    sweep.note_demand(min(remaining,
-                                          sched.want_for(sweep.name,
-                                                         lookahead=1)))
-                # need= only when the whole fleet would otherwise idle
-                cfgs = sweep.poll_ask(want, need=not sched.busy())
-            else:
-                cfgs = sweep.search.ask(want)
+            with span("jx.host.ask", n=want):
+                if sweep.poll_ask is not None:
+                    if sweep.note_demand is not None:
+                        sweep.note_demand(min(remaining, sched.want_for(
+                            sweep.name, lookahead=1)))
+                    # need= only when the whole fleet would otherwise idle
+                    cfgs = sweep.poll_ask(want, need=not sched.busy())
+                else:
+                    cfgs = sweep.search.ask(want)
             if not cfgs:
                 continue
             lids = []
@@ -364,18 +430,20 @@ class ExploreService:
                 lids.append((sweep.local_next, knobs))
                 sweep.local_next += 1
             if sweep.ckpt is not None:
-                # write-ahead in LOCAL ids: the WAL replays identically
-                # whether the sweep resumes solo or under the service
+                # write-ahead in LOCAL ids, BEFORE any dispatch: a crash can
+                # never lose which ids were issued for which knobs, and the
+                # WAL replays identically solo or under the service
                 sweep.ckpt.log_ask([lid for lid, _ in lids],
                                    [knobs for _, knobs in lids])
             for lid, knobs in lids:
                 sweep.outstanding[lid] = knobs
                 self._submit_one(sweep, lid, knobs)
-                sweep.issued += 1
 
-    def _submit_one(self, sweep: TenantSweep, lid: int, knobs: dict) -> None:
-        gid = self._gid_next
-        self._gid_next += 1
+    def _submit_one(self, sweep: TenantSweep, lid: int, knobs: dict,
+                    gid: Optional[int] = None) -> None:
+        if gid is None:
+            gid = self._gid_next
+            self._gid_next += 1
         tc = TestConfig(gid, sweep.arch, sweep.shape, knobs)
         self._by_gid[gid] = (sweep, lid)
         self.scheduler.submit(tc, tenant=sweep.name)
@@ -397,6 +465,8 @@ class ExploreService:
         sched = self.scheduler
         mems = [m for m in msgs if is_membership_msg(m)]
         if mems:
+            # fleet elasticity: HELLO/GOODBYE frames ride the result stream
+            # and never reach the scheduler's result path
             msgs = [m for m in msgs if not is_membership_msg(m)]
             for m in mems:
                 self._on_membership(m)
@@ -406,6 +476,8 @@ class ExploreService:
             for m in ctrls:
                 self._on_control(m)
         if self.fleet_store is not None:
+            # artifact traffic rides the same sockets as results but is the
+            # store's business, not the scheduler's
             arts = [m for m in msgs if is_artifact_msg(m)]
             if arts:
                 msgs = [m for m in msgs if not is_artifact_msg(m)]
@@ -413,21 +485,24 @@ class ExploreService:
                     self.fleet_store.on_message(m, self.transport.push)
             self.fleet_store.tick(self.transport.push)
         if msgs:
-            sched.note_results()       # frame boundary (coalescing detection)
-        for msg in msgs:
-            tc = sched.on_result(msg)
-            if tc is None:
-                continue               # duplicate / orphaned: bookkeeping only
-            entry = self._by_gid.pop(tc.config_id, None)
-            if entry is None:
-                continue               # cancelled while the answer was in flight
-            sweep, lid = entry
-            if "knobs" not in msg:     # slim batch result: rehydrate echo
-                msg["knobs"], msg["arch"], msg["shape"] = \
-                    tc.knobs, tc.arch, tc.shape
-            msg["config_id"] = lid     # back to the tenant-local id space
-            rec = ResultRecord.from_wire(msg)
-            self._record(sweep, rec)
+            with span("jx.host.tell", n=len(msgs),
+                      cid=msgs[0].get("config_id")):
+                sched.note_results()   # frame boundary: coalescing detection
+                for msg in msgs:
+                    tc = sched.on_result(msg)
+                    if tc is None:
+                        continue       # duplicate / orphaned: bookkeeping only
+                    entry = self._by_gid.pop(tc.config_id, None)
+                    if entry is None:
+                        continue       # cancelled while the answer was in flight
+                    sweep, lid = entry
+                    if "knobs" not in msg:   # slim batch result: rehydrate
+                        msg["knobs"], msg["arch"], msg["shape"] = \
+                            tc.knobs, tc.arch, tc.shape
+                    msg["config_id"] = lid   # back to the tenant-local ids
+                    self._record(sweep, ResultRecord.from_wire(msg))
+                    if self.progress and sweep.completed % 10 == 0:
+                        self._print_progress(sweep)
 
         # straggler sweep: requeue survivors, record terminal timeouts
         for tc, client in sched.expire():
@@ -447,12 +522,34 @@ class ExploreService:
             y = np.asarray([rec.metrics[k] for k in sweep.objectives], float)
             sweep.search.tell(rec.knobs, y)
             if sweep.ckpt is not None:
+                # logged AFTER the CSV row + fold: a crash in the window
+                # between them is the "late tell" case restore_sweep
+                # reconciles from the CSV
                 sweep.ckpt.log_tell(rec.config_id, rec.knobs, y)
         for q in sweep.subscribers:
             q.put(rec)
-        if self.progress and sweep.completed % 10 == 0:
-            print(f"[tenancy:{sweep.name}] {sweep.completed}/"
-                  f"{sweep.n_samples}")
+
+    def _print_progress(self, sweep: TenantSweep) -> None:
+        s = self.scheduler.stats()
+        wire = ""
+        if "wire_out_mb" in s:
+            wire = (f", wire {s['wire_out_mb']:.2f}/"
+                    f"{s['wire_in_mb']:.2f} MB {s.get('codec', '?')}")
+        who = "" if self._solo() else f":{sweep.name}"
+        print(f"[jhost{who}] {sweep.completed}/{sweep.n_samples} "
+              f"(inflight={s['inflight']:.0f}, "
+              f"pending={s['pending']:.0f}, "
+              f"chunk~{s['mean_chunk']:.1f}{wire})")
+
+    def _stuck(self, active: List[TenantSweep]) -> None:
+        stats = self.scheduler.stats()
+        for s in active:
+            s.store.close()            # flush the CSV: keep it resumable
+        at = ", ".join(f"{s.completed}/{s.n_samples}"
+                       + ("" if self._solo() else f" ({s.name})")
+                       for s in active)
+        raise RuntimeError(f"all clients quarantined; exploration stuck at "
+                           f"{at} (scheduler stats: {stats})")
 
     def _checkpoint_and_finish(self) -> None:
         for sweep in self._sweeps.values():
@@ -462,19 +559,21 @@ class ExploreService:
                     and sweep.completed - sweep.last_snap
                     >= sweep.checkpoint_every):
                 sweep.last_snap = sweep.completed
-                sweep.ckpt.save({
-                    "version": 1,
-                    "event_pos": sweep.ckpt.log_pos(),
-                    "next_id": sweep.local_next,
-                    "outstanding": list(sweep.outstanding.items()),
-                    "search_state": (sweep.search.state_dict()
-                                     if hasattr(sweep.search, "state_dict")
-                                     else None),
-                    "meta": {"arch": sweep.arch, "shape": sweep.shape,
-                             "n_samples": sweep.n_samples,
-                             "completed": sweep.completed,
-                             "objectives": list(sweep.objectives)},
-                })
+                with span("jx.host.checkpoint"):
+                    sweep.ckpt.save({
+                        "version": 1,
+                        "event_pos": sweep.ckpt.log_pos(),
+                        "next_id": sweep.local_next,
+                        "outstanding": list(sweep.outstanding.items()),
+                        "search_state": (sweep.search.state_dict()
+                                         if hasattr(sweep.search,
+                                                    "state_dict")
+                                         else None),
+                        "meta": {"arch": sweep.arch, "shape": sweep.shape,
+                                 "n_samples": sweep.n_samples,
+                                 "completed": sweep.completed,
+                                 "objectives": list(sweep.objectives)},
+                    })
             if sweep.completed >= sweep.n_samples:
                 sweep.state = "done"
                 self._finalize(sweep)
@@ -483,9 +582,8 @@ class ExploreService:
         if sweep.ckpt is not None:
             sweep.ckpt.close()
             sweep.ckpt = None
-        for q in sweep.subscribers:
+        for q in sweep.subscribers:   # a finished sweep records no more
             q.put(_DONE)
-        sweep.subscribers = []
 
     # -- control plane ---------------------------------------------------------
     def handle_control(self, msg: dict) -> Dict[str, Any]:
@@ -523,25 +621,47 @@ class ExploreService:
     # -- fleet glue ------------------------------------------------------------
     def _tenant_of_client(self, client_id: int) -> Optional[str]:
         """Which tenant the client is most plausibly working for right now:
-        the tenant of its head (running) chunk."""
+        the tenant of its head (running) chunk.  A solo sweep's is not
+        split out: all of the store's work is its own."""
         slot = self.scheduler.slots.get(client_id)
-        if slot is None or not slot.chunks:
+        if self._solo() or slot is None or not slot.chunks:
             return None
         chunk = self.scheduler.chunks.get(slot.chunks[0])
-        return chunk.tenant if chunk is not None else None
+        return None if chunk is None else chunk.tenant
+
+    def _solo(self) -> bool:
+        """A solo sweep: the scheduler's implicit tenant is its only one."""
+        return len(self.scheduler.tenants) == 1
 
     def _on_membership(self, msg: dict) -> None:
-        handle_membership(msg, self.scheduler, self.transport, who="tenancy")
+        """Fold one HELLO/GOODBYE frame into scheduler + transport state;
+        fleet elasticity is tenant-agnostic."""
+        cid = msg.get("client_id")
+        if cid is None:
+            return
+        if msg.get("cmd") == CLIENT_HELLO:
+            if cid not in self.transport.client_ids():
+                try:
+                    self.transport.add_client(cid, msg.get("endpoint"))
+                except NotImplementedError:
+                    print(f"# jhost: client {cid} said hello but "
+                          f"{type(self.transport).__name__} cannot add "
+                          f"push paths — results accepted, no dispatches")
+            slot = self.scheduler.add_client(
+                cid, resident_fps=msg.get("resident_fps"))
+            ci = msg.get("cache_info")
+            if isinstance(ci, dict):
+                slot.shadow.resync(ci.get("currsize"), ci.get("maxsize"))
+        else:                                   # CLIENT_GOODBYE
+            drain = bool(msg.get("drain", True))
+            self.scheduler.remove_client(cid, drain=drain)
+            if not drain:
+                # a hard leave frees the push path now; a draining client
+                # still needs it until its queued chunks complete
+                try:
+                    self.transport.remove_client(cid)
+                except NotImplementedError:
+                    pass
 
     def stop_clients(self) -> None:
-        for c in self.transport.client_ids():
-            try:
-                self.transport.push(c, {"cmd": "stop"})
-            except Exception as e:
-                print(f"# tenancy: client {c} failed to take stop: {e!r}")
-
-    def stats(self) -> Dict[str, Any]:
-        s = self.scheduler.stats()
-        if self.fleet_store is not None:
-            s.update(self.fleet_store.stats())
-        return s
+        stop_clients(self.transport)

@@ -7,7 +7,8 @@ on the host line of the thread that opened it, on the clock of the device's
 ``XLA Ops`` and ``XLA Modules`` lines, with ``stats`` as its arguments.
 
 Every span is named ``jx.<layer>.<phase>`` (README, "Tracing a sweep"):
-``jx.host.*`` in ``JHost``'s loop, ``jx.search.*`` in the model-based
+``jx.host.*`` in the one exploration loop (``ExploreService.run``, which
+``JHost.explore`` runs for a solo sweep), ``jx.search.*`` in the model-based
 searchers, ``jx.gp.*`` in the device GP, ``jx.client.*`` on the board's
 thread, ``jx.build.*`` in the build.  No span sits inside a per-config
 loop and none waits on the device: ``jx.gp.fetch`` wraps the copies back to

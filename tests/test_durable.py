@@ -509,3 +509,193 @@ def test_elastic_join_and_drain_end_to_end(tmp_path):
     assert 2 in served                       # the joiner did real work
     stats = host.scheduler.stats()
     assert stats["clients_joined"] >= 1 and stats["clients_left"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# a solo sweep on a scripted board: frozen output, ids across a crash
+# ---------------------------------------------------------------------------
+
+class _Board(transport.HostTransport):
+    """One board answered in-process and in order: every config pushed
+    comes back on the next pull as a slim result whose metrics are
+    ``_objective`` of its knobs.  The pull numbered ``crash_at`` raises
+    (the host dies with its last dispatch in flight); the frames in
+    ``late`` ride the pull numbered ``late_at``."""
+
+    def __init__(self, crash_at=None, late=(), late_at=None):
+        self.space = _space()
+        self.crash_at, self.late, self.late_at = crash_at, list(late), late_at
+        self.pushes = []       # every message pushed, as pushed
+        self.due = []          # configs pushed and not yet answered
+        self.n_pulls = 0
+
+    def client_ids(self):
+        return [0]
+
+    def push(self, client_id, msg):
+        self.pushes.append(msg)
+        self.due += [m for m in transport.unframe_batch(msg)
+                     if "config_id" in m]
+
+    def pull_many(self, timeout_s):
+        self.n_pulls += 1
+        if self.n_pulls == self.crash_at:
+            raise _Boom()
+        out = [self.answer(m) for m in self.due]
+        self.due = []
+        if self.n_pulls == self.late_at:
+            out = self.late + out
+        return out
+
+    def answer(self, cfg):
+        t, p = _objective(self.space, cfg["knobs"])
+        return {"config_id": cfg["config_id"], "client_id": 0,
+                "status": "ok",
+                "metrics": {"time_s": float(t), "power_w": float(p)}}
+
+
+def _board_leg(tmp_path, board, *, n=30, batch_size=4, dispatch="eager",
+               resume=False):
+    from repro.core import RandomSearch
+
+    class Told(RandomSearch):
+        def tell(self, knobs, y):
+            self.told.append((knobs, [float(v).hex() for v in y]))
+            super().tell(knobs, y)
+
+    search = Told(board.space, seed=3)
+    search.told = []
+    store = ResultStore(csv_path=str(tmp_path / "r.csv"),
+                        knob_names=[k.name for k in board.space],
+                        metric_names=("time_s", "power_w"))
+    try:
+        JHost(board, store, timeout_s=60.0, poll_s=0.0).explore(
+            search, "toy", "gen", n, batch_size=batch_size,
+            dispatch=dispatch, checkpoint_dir=str(tmp_path / "ckpt"),
+            checkpoint_every=8, resume=resume)
+    finally:
+        store.close()
+    return store, search
+
+
+def _sha(obj):
+    import hashlib
+    import json
+
+    if not isinstance(obj, bytes):
+        obj = json.dumps(obj, sort_keys=True).encode()
+    return hashlib.sha256(obj).hexdigest()[:16]
+
+
+def _leg_digests(tmp_path, store, board, search):
+    with open(tmp_path / "r.csv", "rb") as f:
+        csv_bytes = f.read()
+    with open(tmp_path / "ckpt" / ExplorationCheckpoint.EVENTS, "rb") as f:
+        wal = f.read()
+    snap = ExplorationCheckpoint(str(tmp_path / "ckpt")).latest()
+    return {
+        "wire": _sha(board.pushes),
+        "records": _sha([dict(r.to_wire(), metrics={
+            k: float(v).hex() for k, v in r.metrics.items()})
+            for r in store.records]),
+        "told": _sha(search.told),
+        "csv": _sha(csv_bytes), "wal": _sha(wal),
+        "snapshot": _sha({k: snap[k] for k in ("event_pos", "next_id",
+                                               "outstanding", "meta")}),
+    }
+
+
+# what the solo loop put on the wire and on disk before it ran as a
+# one-tenant service, for the straight sweep and its crash + resume
+_FROZEN_SOLO = {
+    (None, "eager"): {
+        "resume_line": "eb8c16cf1258ace8",
+        "straight": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "d7643b7bb1413cd9", "told": "5eb496e30c6923f2",
+            "wal": "c65fdcace7dec025", "wire": "614066eb04c4b97f"},
+        "resumed": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "9618172e2bfcc689", "told": "5eb496e30c6923f2",
+            "wal": "c65fdcace7dec025", "wire": "6486c93afad3261d"},
+    },
+    (4, "eager"): {
+        "resume_line": "a7c7c618897141da",
+        "straight": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "505870ced71367b9", "told": "5eb496e30c6923f2",
+            "wal": "72ed1a8d846116f2", "wire": "4bffee09514ccb09"},
+        "resumed": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "505870ced71367b9", "told": "5eb496e30c6923f2",
+            "wal": "72ed1a8d846116f2", "wire": "4a2b29d740c46021"},
+    },
+    (4, "pipelined"): {
+        "resume_line": "bbcbff84e7b1d800",
+        "straight": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "60fff77b53e91098", "told": "5eb496e30c6923f2",
+            "wal": "cc154b8c5db00c9b", "wire": "4bffee09514ccb09"},
+        "resumed": {
+            "csv": "bfd83e5a855ac109", "records": "754f4713c563ac63",
+            "snapshot": "60fff77b53e91098", "told": "5eb496e30c6923f2",
+            "wal": "cc154b8c5db00c9b", "wire": "4e7350d5cd7f0647"},
+    },
+}
+
+
+@pytest.mark.parametrize("batch_size,dispatch", [
+    (None, "eager"), (4, "eager"), (4, "pipelined")])
+def test_solo_sweep_output_is_frozen(tmp_path, capsys, batch_size, dispatch):
+    """A fixed solo sweep, straight and crashed + resumed: its wire
+    frames, records, tells, CSV, WAL and last snapshot hash to the values
+    the earlier solo loop produced."""
+    got = {}
+    board = _Board()
+    store, search = _board_leg(tmp_path / "u", board, batch_size=batch_size,
+                               dispatch=dispatch)
+    got["straight"] = _leg_digests(tmp_path / "u", store, board, search)
+    with pytest.raises(_Boom):
+        _board_leg(tmp_path / "c", _Board(crash_at=3),
+                   batch_size=batch_size, dispatch=dispatch)
+    capsys.readouterr()
+    board = _Board()
+    store, search = _board_leg(tmp_path / "c", board, batch_size=batch_size,
+                               dispatch=dispatch, resume=True)
+    got["resumed"] = _leg_digests(tmp_path / "c", store, board, search)
+    got["resume_line"] = _sha([ln for ln in capsys.readouterr().out
+                               .splitlines() if ln.startswith("# resume")])
+    assert got == _FROZEN_SOLO[(batch_size, dispatch)]
+
+
+@pytest.mark.parametrize("late_at", [1, 2, 3, 4])
+def test_late_answer_from_before_a_crash_keeps_its_config(tmp_path,
+                                                          late_at):
+    """A board that outlived the host answers, after the resume, the chunk
+    it held at the crash under that chunk's wire ids: each answer either
+    completes the config it was for or is dropped as a duplicate, never
+    completes another config."""
+    crashed = _Board(crash_at=3)
+    with pytest.raises(_Boom):
+        _board_leg(tmp_path, crashed)
+    assert crashed.due                       # a chunk was in flight
+    late = [crashed.answer(m) for m in crashed.due]
+    board = _Board(late=late, late_at=late_at)
+    store, _ = _board_leg(tmp_path, board, resume=True)
+    ids = sorted(r.config_id for r in store.records)
+    assert ids == list(range(30))
+    for r in store.records:
+        t, p = _objective(board.space, r.knobs)
+        assert (r.metrics["time_s"], r.metrics["power_w"]) == (t, p), \
+            r.config_id
+
+
+def test_a_cancelled_solo_sweep_raises(tmp_path):
+    """A wire CANCEL naming the solo sweep ends it early: ``explore``
+    raises rather than return a partial store."""
+    from repro.core.transport import CONTROL_CANCEL
+
+    board = _Board(late=[{"cmd": CONTROL_CANCEL, "tenant": "default"}],
+                   late_at=2)
+    with pytest.raises(RuntimeError, match="cancelled at"):
+        _board_leg(tmp_path, board)

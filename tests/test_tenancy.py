@@ -443,9 +443,72 @@ def test_control_verbs():
     assert not is_control_msg({"cmd": "batch"})
 
 
+class ShadowAware(Replay):
+    """A searcher with the shadow-aware pool hooks, recording what it gets."""
+
+    def __init__(self, ks):
+        super().__init__(ks)
+        self.fp_fn = None
+        self.residency = []
+
+    def set_sw_fingerprint_fn(self, fn):
+        self.fp_fn = fn
+
+    def note_residency(self, fps):
+        self.residency.append(set(fps))
+
+
+def test_service_tenant_gets_the_shadow_aware_pool_hooks():
+    """A tenant with a fingerprint_fn gets the searcher's fingerprint and
+    residency hooks, as a solo sweep does; a tenant without one gets
+    neither."""
+    jc = JConfig(small_space(), n_chips=8)
+    knobs = knob_list(8, 10)
+    aware, plain = ShadowAware(knobs), ShadowAware(knob_list(9, 6))
+    pair, threads = start_fleet(2)
+    svc = ExploreService(pair.host(), timeout_s=60.0, poll_s=0.005,
+                         batch_size=2)
+    svc.submit_sweep("fp", aware, "tpu_v4", "8", len(knobs),
+                     fingerprint_fn=jc.cache_key)
+    svc.submit_sweep("nofp", plain, "tpu_v4", "8", 6)
+    svc.run()
+    stop_fleet(pair, threads)
+    assert aware.fp_fn is not None
+    assert all(aware.fp_fn(k) == jc.cache_key(TestConfig(-1, "tpu_v4", "8", k))
+               for k in knobs)
+    assert any(aware.residency)          # fleet residency reached the pool
+    assert plain.fp_fn is None and plain.residency == []
+
+
+def test_outbound_configs_carry_no_tenant_key():
+    """The loop pushes each config as its four wire fields: the scheduler
+    knows every config's tenant, so no frame carries a tag."""
+
+    class Recording(transport.LoopbackHostTransport):
+        def __init__(self, pair):
+            super().__init__(pair)
+            self.sent = []
+
+        def push(self, client_id, msg):
+            self.sent += transport.unframe_batch(msg)
+            super().push(client_id, msg)
+
+    knobs_a, knobs_b = knob_list(5, 9), knob_list(6, 7)
+    pair, threads = start_fleet(2)
+    host = Recording(pair)
+    svc = ExploreService(host, timeout_s=60.0, poll_s=0.005, batch_size=3)
+    svc.submit_sweep("a", Replay(knobs_a), "tpu_v4", "8", len(knobs_a))
+    svc.submit_sweep("b", Replay(knobs_b), "tpu_v4", "8", len(knobs_b))
+    svc.run()
+    stop_fleet(pair, threads)
+    assert len(host.sent) == len(knobs_a) + len(knobs_b)
+    assert all(set(m) == {"config_id", "arch", "shape", "knobs"}
+               for m in host.sent)
+
+
 def test_wire_tenant_tag_tolerated_by_from_wire():
-    """The sidecar key a service stamps on outbound configs must be
-    invisible to TestConfig.from_wire (old clients)."""
+    """The sidecar key older services stamped on outbound configs must be
+    invisible to TestConfig.from_wire."""
     wire = dict(tc(5).to_wire(), tenant="team-a")
     back = TestConfig.from_wire(wire)
     assert back.config_id == 5 and back.knobs == {"x": 5}

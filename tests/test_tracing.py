@@ -16,11 +16,13 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import get_arch, reduced
-from repro.core import JConfig, JHost, ResultStore
+from repro.core import ExploreService, JConfig, JHost, ResultStore
 from repro.core.search import gp_jax
 from repro.core.search.bayesopt import BayesOpt
 from repro.core.search.gp_jax import JaxIncrementalGP
+from repro.core.tracing import span
 from repro.launch import explore
+from tests.test_tenancy import Replay, knob_list, start_fleet, stop_fleet
 
 Span = collections.namedtuple("Span", "name start end line stats")
 
@@ -165,6 +167,44 @@ def test_a_batch_shares_its_config_id_between_host_and_board(sweep_spans):
     assert sent and sent == cids("jx.client.batch")
     assert {s.stats["n"] for s in sweep_spans
             if s.name == "jx.host.dispatch"} == {4}
+
+
+def test_a_two_tenant_service_spans_its_loop(tmp_path):
+    """Two tenants under one ``ExploreService``: the loop emits every
+    ``jx.host.*`` span, with its arguments, on the thread that runs it."""
+    sizes = {"a": 12, "b": 9}
+    pair, threads = start_fleet(2)
+    svc = ExploreService(pair.host(), timeout_s=60.0, poll_s=0.005,
+                         batch_size=3, checkpoint_root=str(tmp_path / "ck"),
+                         checkpoint_every=4)
+    for seed, (name, n) in enumerate(sizes.items()):
+        svc.submit_sweep(name, Replay(knob_list(seed, n)), "tpu_v4", "8", n)
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with span("jx.test.loop"):
+            svc.run()
+    finally:
+        jax.profiler.stop_trace()
+        stop_fleet(pair, threads)
+    spans = read_spans(trace_dir)
+    host = [s for s in spans if s.name.startswith("jx.host.")]
+    assert {s.name for s in host} == {"jx.host.ask", "jx.host.dispatch",
+                                      "jx.host.pull", "jx.host.tell",
+                                      "jx.host.checkpoint"}
+    assert all(inside(s, {"jx.test.loop"}, spans) for s in host)
+
+    def stats(name):
+        return [s.stats for s in host if s.name == name]
+
+    n = sum(sizes.values())
+    assert sum(st["n"] for st in stats("jx.host.ask")) == n
+    assert sum(st["n"] for st in stats("jx.host.dispatch")) == n
+    assert sum(st["n"] for st in stats("jx.host.tell")) == n
+    assert sum(st["n_msgs"] for st in stats("jx.host.pull")) >= n
+    sent = [st["cid"] for st in stats("jx.host.dispatch")]
+    assert len(set(sent)) == len(sent) and set(sent) <= set(range(n))
+    assert {st["cid"] for st in stats("jx.host.tell")} <= set(range(n))
 
 
 def test_the_gps_other_programs_are_spanned(tmp_path):
